@@ -232,6 +232,9 @@ def main(argv=None) -> None:
         table2_anomalies,
     )
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     args = sys.argv[1:] if argv is None else argv
     if "--check" in args:
         check()

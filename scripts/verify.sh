@@ -12,6 +12,7 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 
 export PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH}
+export JAX_PLATFORMS=cpu  # tests run on the CPU; chip_smoke.py is the chip check
 
 echo "== tier-1 pytest =="
 python -m pytest -x -q

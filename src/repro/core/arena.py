@@ -166,12 +166,9 @@ def _k_bucket(n: int, devices: Optional[int] = None) -> int:
     be doubled into divisibility by e.g. 3 or 6)."""
     b = _bucket(n, 8)
     if devices is None:
-        try:
-            from ..kernels import ops
+        from ..kernels import ops
 
-            devices = ops.merge_mesh_size()
-        except Exception:  # jax unavailable: core stays importable
-            devices = 1
+        devices = ops.merge_mesh_size()
     if b % devices:
         b = math.lcm(b, devices)
     return b
@@ -200,17 +197,15 @@ _DEVICE_TIER_CACHE: Optional[bool] = None
 
 def device_tier_default() -> bool:
     """Whether new arenas keep their slabs device-resident by default:
-    the ``REPRO_DEVICE_TIER`` env knob (1/true/on/yes), forced off when
-    jax is unavailable so ``core`` stays importable without it."""
+    the ``REPRO_DEVICE_TIER`` env knob (1/true/on/yes).  Asking for the
+    tier imports the device ops, so a broken install fails here rather
+    than quietly serving from the host tier."""
     global _DEVICE_TIER_CACHE
     if _DEVICE_TIER_CACHE is None:
         flag = os.environ.get(_DEVICE_TIER_ENV, "").strip().lower()
         on = flag in ("1", "true", "on", "yes")
         if on:
-            try:
-                from ..kernels import ops  # noqa: F401
-            except Exception:
-                on = False
+            from ..kernels import ops  # noqa: F401
         _DEVICE_TIER_CACHE = on
     return _DEVICE_TIER_CACHE
 

@@ -17,7 +17,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from .cache import ExecutorCache
 from .consistency import AnomalyTracker, ProtocolClient, SessionContext
-from .lattices import LamportClock
+from .lattices import LamportClock, Lattice
 from .netsim import NetworkProfile, VirtualClock, DEFAULT_PROFILE
 
 
@@ -51,13 +51,14 @@ class UserLibrary:
         minus the per-key scalar round trips."""
         return self._protocol.get_many(keys)
 
-    def put_many(self, pairs: List[Tuple[str, Any]]) -> None:
+    def put_many(self, pairs: List[Tuple[str, Any]]) -> List[Lattice]:
         """Batched multi-put: per-key session write semantics; the
-        writes leave the cache as ONE batched flush on the next tick."""
-        self._protocol.put_many(pairs)
+        writes leave the cache as ONE batched flush on the next tick.
+        Returns the written lattices (each carries its version)."""
+        return self._protocol.put_many(pairs)
 
-    def put(self, key: str, value: Any) -> None:
-        self._protocol.put(key, value)
+    def put(self, key: str, value: Any) -> Lattice:
+        return self._protocol.put(key, value)
 
     def delete(self, key: str) -> None:
         self._executor.cache.kvs.delete(key)

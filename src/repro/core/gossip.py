@@ -135,9 +135,9 @@ def device_push_sum(values: jax.Array, rounds: int, seed: int = 0) -> jax.Array:
     rng = np.random.default_rng(seed)
     perms = [rng.permutation(n) for _ in range(rounds)]
 
-    from ..launch.mesh import make_auto_mesh
+    from jax.sharding import AxisType
 
-    mesh = make_auto_mesh((n,), ("i",))
+    mesh = jax.make_mesh((n,), ("i",), axis_types=(AxisType.Auto,))
 
     def body(x):
         v = x.reshape(())
@@ -151,8 +151,8 @@ def device_push_sum(values: jax.Array, rounds: int, seed: int = 0) -> jax.Array:
             w = w_half + w_in
         return (v / w).reshape((1,))
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    fn = shard_map(body, mesh=mesh, in_specs=P("i"), out_specs=P("i"))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=P("i"), out_specs=P("i"),
+                       check_vma=False)
     return fn(values)

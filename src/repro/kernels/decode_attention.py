@@ -9,7 +9,8 @@ llama3.2 (24 q heads, 8 kv heads) gives 3 rows per group; we pad groups to
 
 Layout: q (B, Hq, Dh), cache k/v (B, Hkv, S, Dh), lengths (B,) valid-length
 mask -> out (B, Hq, Dh).  Grid (B, Hkv, S//BS) with the KV-block axis
-sequential (streaming-softmax scratch carry).
+sequential (streaming-softmax scratch carry); ``lengths`` rides scalar
+prefetch into SMEM, so each grid step reads its row's length directly.
 """
 
 from __future__ import annotations
@@ -37,7 +38,7 @@ def _decode_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    length = len_ref[0]
+    length = len_ref[pl.program_id(0)]
     col0 = j * bs
 
     @pl.when(col0 < length)
@@ -88,22 +89,26 @@ def decode_attention(
     grid = (B, Hkv, S // bs)
     kernel = functools.partial(_decode_kernel, scale=1.0 / (Dh ** 0.5),
                                bs=bs, group=group)
-    out = pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, j: (b,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, group, Dh), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bs, Dh), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bs, Dh), lambda b, h, j: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, group, Dh), lambda b, h, j, lens: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, bs, Dh), lambda b, h, j, lens: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, bs, Dh), lambda b, h, j, lens: (b, h, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, group, Dh), lambda b, h, j: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, Dh), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, group, Dh),
+                               lambda b, h, j, lens: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((group, 1), jnp.float32),
             pltpu.VMEM((group, 1), jnp.float32),
             pltpu.VMEM((group, Dh), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, Dh), q.dtype),
         interpret=interpret,
-    )(lengths, qg, k_cache, v_cache)
+    )(lengths.astype(jnp.int32), qg, k_cache, v_cache)
     return out.reshape(B, Hq, Dh)

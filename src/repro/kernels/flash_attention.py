@@ -79,7 +79,7 @@ def _flash_kernel(
         o_ref[0, 0] = (acc_ref[...] / safe).astype(o_ref.dtype)
         # log-sum-exp per query row (saved for the flash backward pass)
         lse = m_ref[...] + jnp.log(safe)
-        lse_ref[0, 0] = jnp.where(l == 0.0, NEG_INF, lse)[:, 0]
+        lse_ref[0, 0] = jnp.where(l == 0.0, NEG_INF, lse)
 
 
 @functools.partial(
@@ -96,7 +96,8 @@ def flash_attention(
     block_kv: int = DEFAULT_BS,
     interpret: bool = True,
 ):
-    """Tiled attention.  q (B,Hq,T,Dh); k,v (B,Hkv,S,Dh) -> (B,Hq,T,Dh)."""
+    """Tiled attention.  q (B,Hq,T,Dh); k,v (B,Hkv,S,Dh) -> (B,Hq,T,Dh)
+    and the per-row log-sum-exp (B,Hq,T)."""
     B, Hq, T, Dh = q.shape
     _, Hkv, S, _ = k.shape
     assert Hq % Hkv == 0, (Hq, Hkv)
@@ -109,7 +110,9 @@ def flash_attention(
         _flash_kernel, scale=scale, causal=causal, window=window,
         q_start=q_start, bt=bt, bs=bs,
     )
-    return pl.pallas_call(
+    # lse travels as (B, Hq, T, 1): a (bt, 1) block is tile-aligned on the
+    # chip, where a (1, bt) slice of a (B, Hq, T) array is not
+    o, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -119,11 +122,11 @@ def flash_attention(
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bt, Dh), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bt), lambda b, h, i, j: (b, h, i)),
+            pl.BlockSpec((1, 1, bt, 1), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, Hq, T, Dh), q.dtype),
-            jax.ShapeDtypeStruct((B, Hq, T), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hq, T, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bt, 1), jnp.float32),
@@ -132,3 +135,4 @@ def flash_attention(
         ],
         interpret=interpret,
     )(q, k, v)
+    return o, lse[..., 0]

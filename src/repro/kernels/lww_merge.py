@@ -32,6 +32,15 @@ BK = 8
 BD = 512
 
 
+def _block_d(D: int) -> int:
+    """Payload lanes per block: the widest of 512/256/128 that divides D
+    (so every D % 128 == 0 payload tiles), else the full width."""
+    for bd in (BD, 256, 128):
+        if D % bd == 0:
+            return bd
+    return D
+
+
 def _pred_newer(clock_a, node_a, clock_b, node_b):
     """Lexicographic (clock, node) >= — matches LWWLattice.merge ties."""
     return (clock_a > clock_b) | ((clock_a == clock_b) & (node_a >= node_b))
@@ -58,8 +67,8 @@ def lww_merge(clock_a, node_a, val_a, clock_b, node_b, val_b, *, interpret=True)
       (val, clock, node) of the winning registers.
     """
     K, D = val_a.shape
-    bk, bd = min(BK, K), min(BD, D)
-    assert K % bk == 0 and D % bd == 0, (K, D)
+    bk, bd = min(BK, K), _block_d(D)
+    assert K % bk == 0, (K, D)
     grid = (K // bk, D // bd)
     ts_spec = pl.BlockSpec((bk, 1), lambda i, j: (i, 0))
     val_spec = pl.BlockSpec((bk, bd), lambda i, j: (i, j))
@@ -107,8 +116,8 @@ def _merge_many_kernel(clock_ref, node_ref, val_ref, val_o_ref, clock_o_ref,
 def lww_merge_many(clocks, nodes, vals, *, interpret=True):
     """Reduce R replica batches: clocks/nodes (R, K, 1), vals (R, K, D)."""
     R, K, D = vals.shape
-    bk, bd = min(BK, K), min(BD, D)
-    assert K % bk == 0 and D % bd == 0, (K, D)
+    bk, bd = min(BK, K), _block_d(D)
+    assert K % bk == 0, (K, D)
     # replica axis innermost => sequential with carried scratch accumulator
     grid = (K // bk, D // bd, R)
     ts_spec = pl.BlockSpec((1, bk, 1), lambda i, j, r: (r, i, 0))

@@ -30,7 +30,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 
 from . import ref
 from ..pshard import active_rules
@@ -85,8 +84,8 @@ def _shard_mapped(fn, arg_axes, out_axes, args):
     out_specs = treedef.unflatten(
         [rules.spec_for(ax, s.shape) for ax, s in zip(flat_axes, flat_out)]
     )
-    return shard_map(fn, mesh=rules.mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)(*args)
+    return jax.shard_map(fn, mesh=rules.mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)
 
 
 # ---------------------------------------------------------------------------
@@ -172,8 +171,8 @@ def _k_sharded(name, body, mesh, in_specs, out_specs):
     key = (name, mesh, _BACKEND)
     fn = _SHARDED_FNS.get(key)
     if fn is None:
-        fn = jax.jit(shard_map(body, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=False))
+        fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                   out_specs=out_specs, check_vma=False))
         _SHARDED_FNS[key] = fn
     return fn
 
@@ -255,6 +254,21 @@ def causal_merge(vc_a, val_a, vc_b, val_b):
 # ---------------------------------------------------------------------------
 
 
+def _take_rows(plane, rows):
+    """``jnp.take(plane, rows, axis=0)``, bit-exact on a row-sharded plane.
+
+    The SPMD partitioner gathers from a sharded operand by summing every
+    shard's masked local gather, and -0.0 + 0.0 is +0.0: float payloads
+    would lose the sign of zero (and NaN payload bits).  Gathering the
+    integer bit pattern is exact."""
+    if not jnp.issubdtype(plane.dtype, jnp.floating):
+        return jnp.take(plane, rows, axis=0)
+    bits = jnp.dtype(f"uint{8 * plane.dtype.itemsize}")
+    return jax.lax.bitcast_convert_type(
+        jnp.take(jax.lax.bitcast_convert_type(plane, bits), rows, axis=0),
+        plane.dtype)
+
+
 def slab_sharding(rows: int):
     """NamedSharding for a device slab of ``rows`` rows (None: unsharded)."""
     from ..launch.sharding import kvs_slab_sharding
@@ -296,7 +310,7 @@ def slab_set_row(vals, clocks, nodes, row, clock, rank, flat):
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
 def slab_move_row(vals, clocks, nodes, src, dst):
     """Copy row ``src`` over row ``dst`` (the swap-last delete)."""
-    return (vals.at[dst].set(vals[src]),
+    return (vals.at[dst].set(_take_rows(vals, src)),
             clocks.at[dst].set(clocks[src]),
             nodes.at[dst].set(nodes[src]))
 
@@ -328,7 +342,7 @@ def slab_ingest_rows(vals, clocks, nodes, rows, has, in_clocks, in_nodes,
     """
     a_clocks = jnp.where(has, jnp.take(clocks, rows, axis=0), in_clocks)
     a_nodes = jnp.where(has, jnp.take(nodes, rows, axis=0), in_nodes)
-    a_vals = jnp.where(has, jnp.take(vals, rows, axis=0),
+    a_vals = jnp.where(has, _take_rows(vals, rows),
                        in_vals.astype(vals.dtype))
     win_val, win_clock, win_node = ref.lww_merge_ref(
         a_clocks, a_nodes, a_vals,
@@ -351,9 +365,9 @@ def slab_ingest_multi(vals, clocks, nodes, urows, idx, stored_take,
     pool_nodes = jnp.concatenate(
         [in_nodes, jnp.take(nodes, stored_take, axis=0)])
     pool_vals = jnp.concatenate(
-        [in_vals.astype(vals.dtype), jnp.take(vals, stored_take, axis=0)])
+        [in_vals.astype(vals.dtype), _take_rows(vals, stored_take)])
     win_val, win_clock, win_node = ref.lww_merge_many_ref(
-        pool_clocks[idx], pool_nodes[idx], pool_vals[idx])
+        pool_clocks[idx], pool_nodes[idx], _take_rows(pool_vals, idx))
     return (vals.at[urows].set(win_val),
             clocks.at[urows].set(win_clock),
             nodes.at[urows].set(win_node))
@@ -363,7 +377,7 @@ def slab_ingest_multi(vals, clocks, nodes, urows, idx, stored_take,
 def slab_gather(vals, clocks, nodes, rows):
     """Row gather into fresh buffers (export snapshots: safe against the
     source slab's later donated updates)."""
-    return (jnp.take(vals, rows, axis=0), jnp.take(clocks, rows, axis=0),
+    return (_take_rows(vals, rows), jnp.take(clocks, rows, axis=0),
             jnp.take(nodes, rows, axis=0))
 
 
@@ -371,7 +385,7 @@ def slab_gather(vals, clocks, nodes, rows):
 def slab_row(vals, clocks, nodes, row):
     """One row's (value, clock, rank) — the materialize edge; the caller
     device_gets the triple in a single transfer."""
-    return vals[row], clocks[row, 0], nodes[row, 0]
+    return _take_rows(vals, row), clocks[row, 0], nodes[row, 0]
 
 
 @jax.jit
@@ -391,9 +405,9 @@ def slab_reduce(seg_clocks, seg_nodes, seg_vals, seg_rows, idx):
     pool_nodes = jnp.concatenate(
         [jnp.take(n, r, axis=0) for n, r in zip(seg_nodes, seg_rows)])
     pool_vals = jnp.concatenate(
-        [jnp.take(v, r, axis=0) for v, r in zip(seg_vals, seg_rows)])
+        [_take_rows(v, r) for v, r in zip(seg_vals, seg_rows)])
     return ref.lww_merge_many_ref(
-        pool_clocks[idx], pool_nodes[idx], pool_vals[idx])
+        pool_clocks[idx], pool_nodes[idx], _take_rows(pool_vals, idx))
 
 
 # ---------------------------------------------------------------------------

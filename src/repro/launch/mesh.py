@@ -14,26 +14,12 @@ dry-run's collectives) is the production mesh's.
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 PER_POD = 256  # 16 x 16 chips
-
-
-def _auto_axis_types(n: int) -> dict:
-    """kwargs for explicit Auto axis types — absent on jax < 0.5, where
-    Auto is the only behavior, so omitting the kwarg is equivalent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
-
-
-def make_auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    """Version-portable ``jax.make_mesh`` with Auto-typed axes."""
-    return jax.make_mesh(shape, axes, **_auto_axis_types(len(axes)))
 
 
 def make_merge_mesh(num_devices: Optional[int] = None) -> Optional[Mesh]:
@@ -54,13 +40,13 @@ def make_merge_mesh(num_devices: Optional[int] = None) -> Optional[Mesh]:
     n = jax.local_device_count() if num_devices is None else num_devices
     if n <= 1:
         return None
-    return make_auto_mesh((n,), ("kvs",))
+    return jax.make_mesh((n,), ("kvs",), axis_types=(AxisType.Auto,))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_auto_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def derive_mesh(prod_mesh: Mesh, *, dp: int, ep: int, tp: int) -> Mesh:
@@ -69,7 +55,7 @@ def derive_mesh(prod_mesh: Mesh, *, dp: int, ep: int, tp: int) -> Mesh:
     n_pods = prod_mesh.devices.size // PER_POD
     devices = prod_mesh.devices.reshape(n_pods, dp, ep, tp)
     return Mesh(devices, ("pod", "data", "expert", "model"),
-                **_auto_axis_types(4))
+                axis_types=(AxisType.Auto,) * 4)
 
 
 def mesh_info(mesh: Mesh) -> str:

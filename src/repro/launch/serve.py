@@ -1,10 +1,13 @@
-"""Serving driver: continuous-batched generation with a smoke-scale model.
+"""Serving entry point: continuous-batched generation at the published widths.
 
-Demonstrates the full serving path (per-request prefill -> slot insert ->
+Drives the full serving path (per-request prefill -> slot insert ->
 shared decode steps) for any ``--arch``; families without a batch serving
-path fall back to the legacy lockstep groups inside the engine.
+path fall back to the legacy lockstep groups inside the engine.  Params
+are random, made from ``--seed``.  ``--smoke`` serves the tiny config of
+the same family, for CPU runs.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch mamba2-1.3b --requests 8
+  PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-3b
+  PYTHONPATH=src JAX_PLATFORMS=cpu python -m repro.launch.serve --smoke
 """
 
 from __future__ import annotations
@@ -15,6 +18,7 @@ import time
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import ARCH_IDS, Model, get_config
 from repro.serve import Request, ServingEngine
 
@@ -27,14 +31,18 @@ def main():
     ap.add_argument("--new-tokens", type=int, default=16)
     ap.add_argument("--max-slots", "--batch-size", dest="max_slots",
                     type=int, default=4)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the tiny config (CPU runs)")
     args = ap.parse_args()
 
-    cfg = get_config(args.arch, smoke=True)
+    enable_compile_cache()
+    cfg = get_config(args.arch, smoke=args.smoke)
     model = Model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    params = jax.jit(model.init)(jax.random.PRNGKey(args.seed))
     engine = ServingEngine(model, params, max_slots=args.max_slots,
                            max_len=args.prompt_len + args.new_tokens)
-    rng = np.random.default_rng(0)
+    rng = np.random.default_rng(args.seed)
     reqs = [
         Request(req_id=i,
                 prompt=rng.integers(0, cfg.vocab, args.prompt_len).astype(np.int32),

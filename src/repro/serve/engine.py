@@ -44,12 +44,6 @@ from ..models.model import Model
 from ..obs import MetricsRegistry
 from ..state.tensorstore import tree_from_values, tree_keys
 
-# CPU backends regularly decline KV-cache donation ("Some donated
-# buffers were not usable"); the donation is an optimization, not a
-# correctness requirement, so the advisory warning is just noise here.
-warnings.filterwarnings(
-    "ignore", message="Some donated buffers were not usable")
-
 
 @dataclasses.dataclass
 class Request:
@@ -90,6 +84,12 @@ class ServingEngine:
                  metrics: Optional[MetricsRegistry] = None):
         if not greedy:
             raise NotImplementedError("only greedy decoding is implemented")
+        if jax.default_backend() == "cpu":
+            # the CPU backend declines KV-cache donation, so its advisory
+            # is noise there; on an accelerator the same warning means the
+            # cache is copied every step, so it stays visible
+            warnings.filterwarnings(
+                "ignore", message="Some donated buffers were not usable")
         self.model = model
         self.params = params
         self.max_slots = max_slots

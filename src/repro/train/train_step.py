@@ -17,7 +17,6 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..models.model import Model
@@ -138,7 +137,6 @@ def make_train_step(
 
     assert mesh is not None and "pod" in mesh.shape, \
         "grad compression reduces over the 'pod' axis"
-    in_pod_axes = frozenset(n for n in mesh.axis_names if n != "pod")
 
     def per_pod_grads(params, batch):
         loss, grads = grads_with_accumulation(loss_fn, params, batch,
@@ -160,12 +158,12 @@ def make_train_step(
         pspec = jax.tree.map(lambda _: P(), params)
         bspec = jax.tree.map(lambda _: P("pod"), batch)
         espec = jax.tree.map(lambda _: P(), err_fb)
-        loss, grads, err_new = shard_map(
+        loss, grads, err_new = jax.shard_map(
             inner, mesh=mesh,
             in_specs=(pspec, bspec, espec),
             out_specs=(P(), pspec, espec),
-            check_rep=False,
-            auto=in_pod_axes,
+            axis_names=frozenset({"pod"}),
+            check_vma=False,
         )(params, batch, err_fb)
         params, opt_state = opt.apply_updates(opt_cfg, params, grads, opt_state)
         metrics = {"loss": loss, "grad_norm": opt.global_norm(grads),

@@ -241,8 +241,9 @@ for round_i in range(3):
         clock = int(rng.integers(0, 3))
         node = node_pool[int(rng.integers(0, len(node_pool)))]
         seed = np.random.default_rng(abs(hash((clock, node, k))) % 2**32)
-        lat = LWWLattice((clock, node),
-                         seed.normal(size=(16,)).astype(np.float32))
+        val = seed.normal(size=(16,)).astype(np.float32)
+        val[::5] = -0.0  # the sign of zero must survive sharded gathers
+        lat = LWWLattice((clock, node), val)
         kvs.put(key, lat)
         cur = oracle.get(key)
         oracle[key] = lat if cur is None else cur.merge(lat)
@@ -263,14 +264,16 @@ for node in kvs.nodes.values():
     for key, want in oracle.items():
         got = node.store[key]
         assert got.timestamp == want.timestamp, (key, got.timestamp)
-        np.testing.assert_array_equal(np.asarray(got.value), want.value)
+        np.testing.assert_array_equal(np.asarray(got.value).view(np.uint32),
+                                      want.value.view(np.uint32))
 
 # batched read-repair over sharded device slabs == per-key oracle
 batch = kvs.get_merged_many(list(oracle))
 for key, got in batch.iter_entries():
     want = oracle[key]
     assert got.timestamp == want.timestamp, (key, got.timestamp)
-    np.testing.assert_array_equal(np.asarray(got.value), want.value)
+    np.testing.assert_array_equal(np.asarray(got.value).view(np.uint32),
+                                  want.value.view(np.uint32))
 
 print("DEVICE-SHARDED-OK")
 """

@@ -54,6 +54,24 @@ def test_lww_merge_many_sweep(R):
         np.testing.assert_allclose(np.asarray(o), np.asarray(e))
 
 
+@pytest.mark.parametrize("D", [640, 768])
+def test_lww_merge_many_payload_not_multiple_of_block(D):
+    """D % 128 == 0 but D % 512 != 0: the shape guard in ``ops`` sends
+    such payloads to the Pallas kernel on a TPU, so the kernel must tile
+    them.  Kernel and ``ops.lww_merge_many`` both match the jnp reference
+    bit for bit."""
+    R, K = 3, 16
+    cs = jnp.asarray(RNG.integers(0, 100, (R, K, 1)), jnp.int32)
+    ns = jnp.asarray(RNG.integers(0, 8, (R, K, 1)), jnp.int32)
+    vs = _rand((R, K, D), jnp.float32)
+    exp = ref.lww_merge_many_ref(cs, ns, vs)
+    for out in (lww_many_kernel(cs, ns, vs, interpret=True),
+                ops.lww_merge_many(cs, ns, vs)):
+        for o, e in zip(out, exp):
+            np.testing.assert_array_equal(
+                np.asarray(o).view(np.uint32), np.asarray(e).view(np.uint32))
+
+
 @pytest.mark.parametrize("K,N", [(8, 4), (32, 16), (64, 64)])
 def test_vc_join_classify_sweep(K, N):
     a = jnp.asarray(RNG.integers(0, 6, (K, N)), jnp.int32)

@@ -95,8 +95,9 @@ def test_zero1_adds_data_axis():
     params = model.abstract_params()
     plan = shlib.plan_for("llama3.2-3b")
     # use a real (tiny) mesh so NamedSharding construction works
-    from repro.launch.mesh import make_auto_mesh
-    mesh = make_auto_mesh((1, 1, 1, 1), ("pod", "data", "expert", "model"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((1, 1, 1, 1), ("pod", "data", "expert", "model"),
+                         axis_types=(AxisType.Auto,) * 4)
     r = ShardRules(mesh=mesh, rules=shlib.logical_rules(plan, FakeMesh(
         {"pod": 1, "data": 32, "expert": 1, "model": 8})).rules)
     # spec_for uses rule sizes from the fake mesh; just check the resolver

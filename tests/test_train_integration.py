@@ -73,14 +73,12 @@ def test_chunked_ce_inside_model_loss():
 
 def _run_compress_once(g, err):
     """quantize_psum_pod on a trivial 1-device 'pod' mesh."""
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
-    from repro.launch.mesh import make_auto_mesh
+    from jax.sharding import AxisType, PartitionSpec as P
     from repro.train.train_step import quantize_psum_pod
-    mesh = make_auto_mesh((1,), ("pod",))
-    fn = shard_map(quantize_psum_pod, mesh=mesh,
-                   in_specs=(P(), P()), out_specs=(P(), P()),
-                   check_rep=False)
+    mesh = jax.make_mesh((1,), ("pod",), axis_types=(AxisType.Auto,))
+    fn = jax.shard_map(quantize_psum_pod, mesh=mesh,
+                       in_specs=(P(), P()), out_specs=(P(), P()),
+                       check_vma=False)
     return fn(g, err)
 
 
