@@ -56,10 +56,6 @@ from repro.serve import Request, ServingEngine, make_pipeline_stages  # noqa: E4
 RECORD_FLOATS = 256  # YCSB's default record: 10 fields x 100 B, ~1 KiB
 ZIPF_THETA = 0.99  # YCSB's request-distribution constant
 N_KEYS = 1 << 20  # YCSB recordcount: ~1 GiB per replica copy
-# --chips 4 loads the store twice (sharded, then single-device) and the
-# load is host-bound (~160 us/key on the four-chip host), so it runs the
-# smallest store this script calls real
-N_KEYS_4 = 1 << 18
 # Served vs reference logits: both run the same bf16 weights and
 # activations; only the attention kernels differ (Pallas flash/decode vs
 # materialized jnp softmax).  A bf16 value carries 8 significant bits, so
@@ -116,12 +112,12 @@ def peak_bytes() -> list:
             for d in jax.devices()]
 
 
-def report(tag: str, comp: "CompileLog", mark) -> None:
+def report(tag: str, comp: "CompileLog", mark, log=print) -> None:
     st = comp.since(mark)
-    print(f"{tag}: {st['compile_s']:.1f} s compiling ({st['compiles']} "
-          f"programs, {st['cache_hits']} persistent-cache hits), peak "
-          f"device bytes {peak_bytes()}")
-    print(f"{tag}: compiled {dict(sorted(st['compiled'].items()))}")
+    log(f"{tag}: {st['compile_s']:.1f} s compiling ({st['compiles']} "
+        f"programs, {st['cache_hits']} persistent-cache hits), peak "
+        f"device bytes {peak_bytes()}")
+    log(f"{tag}: compiled {dict(sorted(st['compiled'].items()))}")
 
 
 # ---------------------------------------------------------------------------
@@ -340,10 +336,45 @@ def store_phase(seed: int, n_keys: int, *, n_dag_calls: int = 320,
     out["transfer"] = xfer
     node0 = next(iter(kvs.nodes.values()))
     out["slab_shardings"] = {
-        f"{shape}/{dtype}": str(slab.vals.sharding)
+        f"{shape}/{dtype}": slab.vals.sharding
         for (shape, dtype), slab in node0.engine.arena._slabs.items()}
     out["merged"] = (merged_vals, merged_clock, merged_node)
     return out
+
+
+def sharded_store_phase(seed: int, n_keys: int, comp: "CompileLog", *,
+                        log=print, **kw) -> None:
+    """``--chips 4``: the store phase with its slabs K-sharded over the
+    host's chips, then again on one device (``ops.set_merge_mesh(None)``).
+    Each run checks its own oracle; the two read-backs must agree bit
+    for bit.  Both use a seeded ``RandomPolicy``, so both make the same
+    writes (see ``store_phase``)."""
+    chips = ops.merge_mesh_size()
+    if chips < 2:
+        raise RuntimeError("no multi-device merge mesh")
+    mark = comp.window()
+    sharded = store_phase(seed, n_keys, scheduler_policy=RandomPolicy(),
+                          log=log, **kw)
+    report(f"store[K-sharded over {chips} chips]", comp, mark, log)
+    for group, sharding in sharded["slab_shardings"].items():
+        log(f"store: slab {group} planes: {sharding}")
+        spec = getattr(sharding, "spec", ())
+        if not spec or spec[0] != "kvs":
+            raise AssertionError(f"slab {group} is not K-sharded")
+    gc.collect()  # the sharded cluster's slabs leave the chips
+    ops.set_merge_mesh(None)
+    mark = comp.window()
+    single = store_phase(seed, n_keys, scheduler_policy=RandomPolicy(),
+                         log=log, **kw)
+    report("store[single device]", comp, mark, log)
+    for a, b, name in zip(sharded["merged"], single["merged"],
+                          ("values", "clocks", "nodes")):
+        same = (np.array_equal(a.view(np.uint32), b.view(np.uint32))
+                if a.dtype == np.float32 else np.array_equal(a, b))
+        if not same:
+            raise AssertionError(f"sharded and single-device {name} differ")
+    log(f"store: sharded read-back equals the single-device run bit for "
+        f"bit over {n_keys} keys")
 
 
 # ---------------------------------------------------------------------------
@@ -352,25 +383,17 @@ def store_phase(seed: int, n_keys: int, *, n_dag_calls: int = 320,
 
 
 def _first_logits(model, params, prompts, buckets):
-    """Last-prompt-position logits per prompt, one padded
-    ``prefill_batch`` per bucket.  A fresh jit each call: the kernel
-    backend is read when the function is traced."""
-    fn = jax.jit(lambda p, t, n: model.prefill_batch(p, t, n)[0][:, 0])
-    by_bucket = collections.defaultdict(list)
-    for i, p in enumerate(prompts):
-        by_bucket[next(b for b in buckets if len(p) <= b)].append(i)
-    out = {}
-    for bucket, idxs in sorted(by_bucket.items()):
-        rows = 1 << (len(idxs) - 1).bit_length()
-        toks = np.zeros((rows, bucket), np.int32)
-        lens = np.ones((rows,), np.int32)
-        for j, i in enumerate(idxs):
-            toks[j, :len(prompts[i])] = prompts[i]
-            lens[j] = len(prompts[i])
-        logits = np.asarray(fn(params, toks, lens))
-        for j, i in enumerate(idxs):
-            out[i] = logits[j]
-    return [out[i] for i in range(len(prompts))]
+    """Last-prompt-position logits per prompt, each prefilled alone at
+    B=1 in its bucket as ``ServingEngine`` does.  A fresh jit each call:
+    the kernel backend is read when the function is traced."""
+    fn = jax.jit(lambda p, t, n: model.prefill_batch(p, t, n)[0][0, -1])
+    out = []
+    for p in prompts:
+        toks = np.zeros((1, next(b for b in buckets if len(p) <= b)), np.int32)
+        toks[0, :len(p)] = p
+        out.append(np.asarray(fn(params, toks, np.asarray([len(p)], np.int32)),
+                              np.float32))
+    return out
 
 
 def _teacher_forced_logits(model, params, seqs, positions, width):
@@ -424,7 +447,8 @@ def serve_phase(seed: int, cfg, *, n_requests: int = 8, new_tokens: int = 32,
     log(f"serve: ServingEngine {n_requests} requests x {new_tokens} tokens "
         f"in {out['engine_s']:.1f} s (compiles included), "
         f"stats={engine.stats}")
-    served_logits = _first_logits(model, params, prompts, buckets)
+    # the logits the engine served from: each request's own prefill
+    served_logits = [np.asarray(r.first_logits, np.float32) for r in reqs]
 
     # -- the section 6.3.1 pipeline as a Cloudburst DAG -------------------
     t0 = time.perf_counter()
@@ -539,31 +563,7 @@ def main(argv=None) -> int:
           f"visible, jax {jax.__version__}, compile cache {cache_dir}")
 
     if args.chips == 4:
-        print(f"store: {N_KEYS_4} keys, not {N_KEYS}: the store phase runs "
-              f"twice here and its load is host-bound")
-        # the comparison needs identical writes in both runs: seeded
-        # placement (see store_phase)
-        mark = comp.window()
-        sharded = store_phase(args.seed, N_KEYS_4,
-                              scheduler_policy=RandomPolicy())
-        report(f"store[K-sharded over {ops.merge_mesh_size()} chips]", comp,
-               mark)
-        for group, sharding in sharded["slab_shardings"].items():
-            print(f"store: slab {group} planes: {sharding}")
-        gc.collect()  # the sharded cluster's slabs leave the chips
-        ops.set_merge_mesh(None)
-        mark = comp.window()
-        single = store_phase(args.seed, N_KEYS_4,
-                             scheduler_policy=RandomPolicy())
-        report("store[single device]", comp, mark)
-        for a, b, name in zip(sharded["merged"], single["merged"],
-                              ("values", "clocks", "nodes")):
-            same = (np.array_equal(a.view(np.uint32), b.view(np.uint32))
-                    if a.dtype == np.float32 else np.array_equal(a, b))
-            if not same:
-                raise AssertionError(f"sharded and single-device {name} differ")
-        print(f"store: sharded read-back equals the single-device run "
-              f"bit for bit over {N_KEYS_4} keys")
+        sharded_store_phase(args.seed, N_KEYS, comp)
     else:
         mark = comp.window()
         store = store_phase(args.seed, N_KEYS)

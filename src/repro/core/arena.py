@@ -111,6 +111,21 @@ _JAX_DOWNCAST_DTYPES = frozenset(
 )
 
 
+# ``dtype.name`` is rebuilt on every access, too slow for per-key paths:
+# dtype -> (name, plane-eligible), memoized
+_DTYPE_INFO: Dict[np.dtype, Tuple[str, bool]] = {}
+
+
+def _dtype_info(dtype: np.dtype) -> Tuple[str, bool]:
+    info = _DTYPE_INFO.get(dtype)
+    if info is None:
+        name = dtype.name
+        info = (name, name not in _JAX_DOWNCAST_DTYPES and (
+            dtype.kind in "biufc" or name.startswith(("bfloat16", "float8"))))
+        _DTYPE_INFO[dtype] = info
+    return info
+
+
 def tensor_payload(value: Any) -> Optional[np.ndarray]:
     """Return the payload as an ndarray if it is dense tensor data the
     batched plane can carry losslessly."""
@@ -122,11 +137,9 @@ def tensor_payload(value: Any) -> Optional[np.ndarray]:
             arr = np.asarray(value)
         except Exception:
             return None
-    if arr is None or arr.dtype.name in _JAX_DOWNCAST_DTYPES:
+    if arr is None or not _dtype_info(arr.dtype)[1]:
         return None
-    if arr.dtype.kind in "biufc" or arr.dtype.name.startswith(("bfloat16", "float8")):
-        return arr
-    return None
+    return arr
 
 
 def is_arena_lww(lattice: Any) -> bool:
@@ -558,7 +571,7 @@ class PlaneBuffer:
         if is_arena_lww(value):
             arr = tensor_payload(value.value)
             clock, node_id = value.timestamp
-            acc = self._accum((tuple(arr.shape), arr.dtype.name),
+            acc = self._accum((tuple(arr.shape), _dtype_info(arr.dtype)[0]),
                               tuple(arr.shape), arr.dtype)
             acc.add_row(key, arr.reshape(-1), clock, self._intern(node_id))
         else:
@@ -830,17 +843,28 @@ class _DeviceSlab:
 
         R, U = idx.shape
         Rp, Up = _bucket(R, 2), _k_bucket(U)
+        kk, S = len(clocks), len(stored_take)
+        if not _is_device(vals):
+            # host rows pad to the K bucket like ``_pad_np`` (pad lanes
+            # are never indexed), which moves the stored candidates up
+            kin = _k_bucket(kk)
+            pad = [(0, kin - kk), (0, 0)]
+            clocks = np.pad(np.asarray(clocks, np.int32), pad)
+            ranks = np.pad(np.asarray(ranks, np.int32), pad)
+            vals = np.pad(np.asarray(vals), pad)
+            idx = np.where(idx >= kk, idx + (kin - kk), idx)
+            self.xfer.h2d_bytes += vals.nbytes + clocks.nbytes + ranks.nbytes
+        take = np.full(_bucket(S, 8), self.scratch, np.int32)
+        take[:S] = stored_take
         urows_in = np.full(Up, self.scratch, np.int32)
         urows_in[:U] = urows
         idx_in = np.empty((Rp, Up), np.int32)
         idx_in[:R, :U] = idx
         idx_in[R:, :U] = idx[0]       # repeat a candidate: idempotent
         idx_in[:, U:] = idx[0, 0]     # pad columns all write one winner
-        if not _is_device(vals):
-            self.xfer.h2d_bytes += vals.nbytes + clocks.nbytes + ranks.nbytes
         self.vals, self.clocks, self.nodes = ops.slab_ingest_multi(
-            self.vals, self.clocks, self.nodes, urows_in, idx_in,
-            np.asarray(stored_take, np.int32), clocks, ranks, vals)
+            self.vals, self.clocks, self.nodes, urows_in, idx_in, take,
+            clocks, ranks, vals)
 
 
 class LatticeArena:
@@ -892,7 +916,7 @@ class LatticeArena:
     # -- plumbing -------------------------------------------------------------
     @staticmethod
     def group_of(arr: np.ndarray) -> _GroupKey:
-        return (tuple(arr.shape), arr.dtype.name)
+        return (tuple(arr.shape), _dtype_info(arr.dtype)[0])
 
     def _remap_ranks(self, remap: np.ndarray) -> None:
         for slab in self._slabs.values():
@@ -1188,7 +1212,7 @@ class _ReduceGroupPlan:
     execute, so a cached plan always reduces the newest planes."""
 
     __slots__ = ("group", "keys", "segs", "idx", "R", "device",
-                 "idx_dev", "rows32")
+                 "idx_dev", "rows32", "dev_slabs")
 
     def __init__(self, group: _GroupKey, keys: List[str],
                  segs: list, idx: np.ndarray, R: int):
@@ -1201,6 +1225,7 @@ class _ReduceGroupPlan:
             isinstance(s, _DeviceSlab) for s, _, _ in segs)
         self.idx_dev: Optional[np.ndarray] = None
         self.rows32: Optional[List[np.ndarray]] = None
+        self.dev_slabs: Optional[List[_DeviceSlab]] = None
 
 
 class _ReducePlan:
@@ -1847,14 +1872,32 @@ class MergeEngine:
                 segs.append((slab, rows, span))
             gp = _ReduceGroupPlan(group, list(keys), segs, idx, R)
             if gp.device:
-                # fused-jit form: int32 rows + a K-bucketed index matrix
-                # (pad columns repeat one candidate; winners slice [:K])
+                # fused-jit form, shaped so the jit sees few programs:
+                # segments in capacity order, each segment's rows padded
+                # to a power-of-two bucket (pad lanes re-gather its first
+                # row and are never indexed), and a K-bucketed index
+                # matrix (pad columns repeat one candidate; winners
+                # slice [:K])
+                lens = np.asarray([len(r) for _, r, _ in segs], np.int64)
+                old_base = np.cumsum(lens) - lens
+                new_base = np.empty_like(old_base)
+                order = sorted(range(len(segs)), key=lambda i: segs[i][0].cap)
+                rows32, off = [], 0
+                for i in order:
+                    new_base[i] = off
+                    rows = np.full(_bucket(int(lens[i]), 8), segs[i][1][0],
+                                   np.int32)
+                    rows[:lens[i]] = segs[i][1]
+                    rows32.append(rows)
+                    off += len(rows)
+                seg_of = np.searchsorted(old_base, idx, side="right") - 1
                 Kp = _k_bucket(K)
                 idx_dev = np.empty((Rp, Kp), np.int32)
-                idx_dev[:, :K] = idx
-                idx_dev[:, K:] = idx[0, 0]
+                idx_dev[:, :K] = idx + (new_base - old_base)[seg_of]
+                idx_dev[:, K:] = idx_dev[0, 0]
                 gp.idx_dev = idx_dev
-                gp.rows32 = [np.asarray(r, np.int32) for _, r, _ in segs]
+                gp.rows32 = rows32
+                gp.dev_slabs = [segs[i][0] for i in order]
             group_plans.append(gp)
         return _ReducePlan(leftover, group_plans)
 
@@ -1938,9 +1981,9 @@ class MergeEngine:
         from ..kernels import ops
 
         win_val, win_clock, win_node = ops.slab_reduce(
-            [s.clocks for s, _, _ in g.segs],
-            [s.nodes for s, _, _ in g.segs],
-            [s.vals for s, _, _ in g.segs],
+            [s.clocks for s in g.dev_slabs],
+            [s.nodes for s in g.dev_slabs],
+            [s.vals for s in g.dev_slabs],
             list(g.rows32), g.idx_dev)
         keys = g.keys
         K = len(keys)

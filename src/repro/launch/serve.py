@@ -3,8 +3,8 @@
 Drives the full serving path (per-request prefill -> slot insert ->
 shared decode steps) for any ``--arch``; families without a batch serving
 path fall back to the legacy lockstep groups inside the engine.  Params
-are random, made from ``--seed``.  ``--smoke`` serves the tiny config of
-the same family, for CPU runs.
+are random, made from seed 0.  ``--smoke`` serves the tiny config of the
+same family, for CPU runs.
 
   PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-3b
   PYTHONPATH=src JAX_PLATFORMS=cpu python -m repro.launch.serve --smoke
@@ -31,7 +31,6 @@ def main():
     ap.add_argument("--new-tokens", type=int, default=16)
     ap.add_argument("--max-slots", "--batch-size", dest="max_slots",
                     type=int, default=4)
-    ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--smoke", action="store_true",
                     help="serve the tiny config (CPU runs)")
     args = ap.parse_args()
@@ -39,10 +38,10 @@ def main():
     enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     model = Model(cfg)
-    params = jax.jit(model.init)(jax.random.PRNGKey(args.seed))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))
     engine = ServingEngine(model, params, max_slots=args.max_slots,
                            max_len=args.prompt_len + args.new_tokens)
-    rng = np.random.default_rng(args.seed)
+    rng = np.random.default_rng(0)
     reqs = [
         Request(req_id=i,
                 prompt=rng.integers(0, cfg.vocab, args.prompt_len).astype(np.int32),

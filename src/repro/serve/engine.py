@@ -52,6 +52,9 @@ class Request:
     max_new_tokens: int = 16
     out_tokens: List[int] = dataclasses.field(default_factory=list)
     done: bool = False
+    # (V,) logits at the last prompt position, from this request's own
+    # prefill; left on the device (continuous-batching families only)
+    first_logits: Optional[jax.Array] = None
 
 
 def _pow2_buckets(lo: int, hi: int) -> Tuple[int, ...]:
@@ -133,8 +136,9 @@ class ServingEngine:
     # -- jitted steps ------------------------------------------------------
     def _prefill_fn(self, params, tokens, lengths):
         logits, pcache = self.model.prefill_batch(params, tokens, lengths)
-        tok0 = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-        return tok0, pcache
+        last = logits[:, -1, :]
+        tok0 = jnp.argmax(last, axis=-1).astype(jnp.int32)
+        return tok0, last, pcache
 
     def _decode_fn(self, params, tokens, cache):
         logits, cache = self.model.decode_step_batch(params, tokens, cache)
@@ -221,10 +225,11 @@ class ServingEngine:
         bucket = self._bucket(P)
         tokens = np.zeros((1, bucket), np.int32)
         tokens[0, :P] = req.prompt
-        tok0, pcache = self._prefill(
+        tok0, last, pcache = self._prefill(
             self.params, jnp.asarray(tokens),
             jnp.asarray([P], jnp.int32))
         self._m_prefills.inc()
+        req.first_logits = last[0]
         req.out_tokens.append(int(tok0[0]))
         self._m_tokens.inc()
         if len(req.out_tokens) >= req.max_new_tokens:

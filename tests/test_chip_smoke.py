@@ -8,6 +8,8 @@ itself must refuse to run anywhere but a TPU.
 
 import importlib.util
 import os
+import subprocess
+import sys
 from pathlib import Path
 
 import pytest
@@ -48,6 +50,30 @@ def test_store_phase_reads_back_every_write(chip_smoke, monkeypatch):
     assert out["verified_replica_copies"] == 2 << 10
     assert out["verified_causal_writes"] == 8
     assert out["dag_writes"] > 0
+
+
+# ``--chips 4`` rehearsed on four virtual CPU devices (jax fixes its
+# device count at backend init, so it runs in a subprocess)
+_SHARDED = """
+import importlib.util
+spec = importlib.util.spec_from_file_location("chip_smoke", "chip_smoke.py")
+mod = importlib.util.module_from_spec(spec)
+spec.loader.exec_module(mod)
+mod.sharded_store_phase(0, 1 << 10, mod.CompileLog(), n_dag_calls=32,
+                        n_causal=8, chunk=256)
+"""
+
+
+def test_sharded_store_phase_matches_single_device():
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    env.pop("REPRO_DEVICE_TIER", None)
+    proc = subprocess.run([sys.executable, "-c", _SHARDED], cwd=ROOT,
+                          env=env, capture_output=True, text=True,
+                          timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert "PartitionSpec('kvs', None)" in proc.stdout
+    assert "equals the single-device run bit for bit" in proc.stdout
 
 
 def test_serve_phase_matches_reference(chip_smoke):

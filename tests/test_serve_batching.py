@@ -194,6 +194,27 @@ def test_engine_prefill_only_request_frees_slot():
     assert one.out_tokens[0] == two.out_tokens[0]  # same prompt, same argmax
 
 
+def test_engine_keeps_each_requests_prefill_logits():
+    """``Request.first_logits`` is the row the first token was taken
+    from: the request's own B=1 prefill at its prompt bucket."""
+    cfg, model, params = _setup("llama3.2-3b")
+    eng = ServingEngine(model, params, max_slots=2, max_len=32)
+    reqs = [Request(req_id=i, prompt=p, max_new_tokens=3)
+            for i, p in enumerate(_prompts(cfg, [5, 12, 9]))]
+    eng.generate(reqs)
+    for r in reqs:
+        got = np.asarray(r.first_logits)
+        assert got.shape == (cfg.vocab,)
+        assert int(np.argmax(got)) == r.out_tokens[0]
+        bucket = eng._bucket(len(r.prompt))
+        toks = np.zeros((1, bucket), np.int32)
+        toks[0, :len(r.prompt)] = r.prompt
+        want, _ = model.prefill_batch(params, jnp.asarray(toks),
+                                      jnp.asarray([len(r.prompt)], jnp.int32))
+        np.testing.assert_allclose(got, np.asarray(want[0, -1]),
+                                   atol=2e-3, rtol=2e-3)
+
+
 # -- cluster-engine wave batching (batch_call hook) ------------------------
 
 class _BatchStub:
