@@ -51,6 +51,7 @@ from repro.core import (  # noqa: E402
 from repro.kernels import ops  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.models import Model, get_config  # noqa: E402
+from repro.obs import host as obs_host  # noqa: E402
 from repro.serve import Request, ServingEngine, make_pipeline_stages  # noqa: E402
 
 RECORD_FLOATS = 256  # YCSB's default record: 10 fields x 100 B, ~1 KiB
@@ -72,38 +73,9 @@ def key_index(key: str) -> int:
 
 
 # ---------------------------------------------------------------------------
-# compile accounting: JAX reports every backend compile (and persistent
-# cache hit) through its monitoring hooks, with the compiled function's name
+# compile and memory accounting: the program's own ``host.jit.*``
+# counters (``repro.obs.host``), read as deltas around each phase
 # ---------------------------------------------------------------------------
-
-
-class CompileLog:
-    def __init__(self) -> None:
-        self.seconds = 0.0
-        self.names: collections.Counter = collections.Counter()
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, event: str, duration: float, **kw) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
-            self.names[kw.get("fun_name", "?")] += 1
-
-    def _on_event(self, event: str, **kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def window(self):
-        return (self.seconds, sum(self.names.values()), self.cache_hits,
-                collections.Counter(self.names))
-
-    def since(self, mark):
-        s0, n0, h0, names0 = mark
-        return {"compile_s": self.seconds - s0,
-                "compiles": sum(self.names.values()) - n0,
-                "cache_hits": self.cache_hits - h0,
-                "compiled": self.names - names0}
 
 
 def peak_bytes() -> list:
@@ -112,12 +84,13 @@ def peak_bytes() -> list:
             for d in jax.devices()]
 
 
-def report(tag: str, comp: "CompileLog", mark, log=print) -> None:
-    st = comp.since(mark)
-    log(f"{tag}: {st['compile_s']:.1f} s compiling ({st['compiles']} "
-        f"programs, {st['cache_hits']} persistent-cache hits), peak "
-        f"device bytes {peak_bytes()}")
-    log(f"{tag}: compiled {dict(sorted(st['compiled'].items()))}")
+def report(tag: str, mark, log=print) -> None:
+    """Compiles since ``mark`` (an ``obs_host.snapshot()``) and the
+    devices' peak bytes."""
+    now = obs_host.snapshot()
+    log(f"{tag}: {now['host.jit.compile_s'] - mark['host.jit.compile_s']:.1f}"
+        f" s compiling ({now['host.jit.compiles'] - mark['host.jit.compiles']}"
+        f" programs), peak device bytes {peak_bytes()}")
 
 
 # ---------------------------------------------------------------------------
@@ -342,8 +315,8 @@ def store_phase(seed: int, n_keys: int, *, n_dag_calls: int = 320,
     return out
 
 
-def sharded_store_phase(seed: int, n_keys: int, comp: "CompileLog", *,
-                        log=print, **kw) -> None:
+def sharded_store_phase(seed: int, n_keys: int, *, log=print,
+                        **kw) -> None:
     """``--chips 4``: the store phase with its slabs K-sharded over the
     host's chips, then again on one device (``ops.set_merge_mesh(None)``).
     Each run checks its own oracle; the two read-backs must agree bit
@@ -352,10 +325,11 @@ def sharded_store_phase(seed: int, n_keys: int, comp: "CompileLog", *,
     chips = ops.merge_mesh_size()
     if chips < 2:
         raise RuntimeError("no multi-device merge mesh")
-    mark = comp.window()
+    obs_host.install()
+    mark = obs_host.snapshot()
     sharded = store_phase(seed, n_keys, scheduler_policy=RandomPolicy(),
                           log=log, **kw)
-    report(f"store[K-sharded over {chips} chips]", comp, mark, log)
+    report(f"store[K-sharded over {chips} chips]", mark, log)
     for group, sharding in sharded["slab_shardings"].items():
         log(f"store: slab {group} planes: {sharding}")
         spec = getattr(sharding, "spec", ())
@@ -363,10 +337,10 @@ def sharded_store_phase(seed: int, n_keys: int, comp: "CompileLog", *,
             raise AssertionError(f"slab {group} is not K-sharded")
     gc.collect()  # the sharded cluster's slabs leave the chips
     ops.set_merge_mesh(None)
-    mark = comp.window()
+    mark = obs_host.snapshot()
     single = store_phase(seed, n_keys, scheduler_policy=RandomPolicy(),
                          log=log, **kw)
-    report("store[single device]", comp, mark, log)
+    report("store[single device]", mark, log)
     for a, b, name in zip(sharded["merged"], single["merged"],
                           ("values", "clocks", "nodes")):
         same = (np.array_equal(a.view(np.uint32), b.view(np.uint32))
@@ -558,22 +532,22 @@ def main(argv=None) -> int:
               f"{len(devices)} devices", file=sys.stderr)
         return 1
     cache_dir = enable_compile_cache()
-    comp = CompileLog()
+    obs_host.install()
     print(f"device: {dev.device_kind} ({dev.platform}), {len(devices)} "
           f"visible, jax {jax.__version__}, compile cache {cache_dir}")
 
     if args.chips == 4:
-        sharded_store_phase(args.seed, N_KEYS, comp)
+        sharded_store_phase(args.seed, N_KEYS)
     else:
-        mark = comp.window()
+        mark = obs_host.snapshot()
         store = store_phase(args.seed, N_KEYS)
-        report("store", comp, mark)
+        report("store", mark)
         del store
         gc.collect()
         cfg = get_config("llama3.2-3b")
-        mark = comp.window()
+        mark = obs_host.snapshot()
         serve_phase(args.seed, cfg)
-        report("serve", comp, mark)
+        report("serve", mark)
         print(kvs_resident_note(cfg))
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

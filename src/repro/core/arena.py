@@ -84,6 +84,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import math
 import os
+import time
 import weakref
 
 try:  # MutableMapping moved in 3.10
@@ -248,15 +249,17 @@ class _XferStats:
     device->host sync events; tiny row-index/scalar uploads are control
     plane and uncounted.  The zero-host-sync acceptance asserts ride
     these: steady-state device-tier gossip and warmed batched reads must
-    leave all three counters unchanged.
+    leave the counters unchanged.  ``sync_s`` is the host wall time spent
+    blocked in those device->host copies.
     """
 
-    __slots__ = ("h2d_bytes", "d2h_bytes", "device_syncs")
+    __slots__ = ("h2d_bytes", "d2h_bytes", "device_syncs", "sync_s")
 
     def __init__(self) -> None:
         self.h2d_bytes = 0
         self.d2h_bytes = 0
         self.device_syncs = 0
+        self.sync_s = 0.0
 
 
 # ---------------------------------------------------------------------------
@@ -422,8 +425,10 @@ class PlaneBatch:
         per device group against ``xfer`` when given."""
         out = PlaneBatch(self.node_ids)
         for group, pg in self.groups.items():
+            t0 = time.perf_counter()
             host = pg.to_host()
             if xfer is not None and host is not pg:
+                xfer.sync_s += time.perf_counter() - t0
                 xfer.device_syncs += 1
                 xfer.d2h_bytes += (host.vals.nbytes + host.clocks.nbytes
                                    + host.node_idx.nbytes)
@@ -905,6 +910,10 @@ class LatticeArena:
     def device_syncs(self) -> int:
         return self._xfer.device_syncs
 
+    @property
+    def device_sync_s(self) -> float:
+        return self._xfer.sync_s
+
     def reset_transfer_stats(self) -> None:
         """Zero the transfer counters in place — the slabs alias this
         ``_XferStats`` object, so benches/tests can window device-tier
@@ -912,6 +921,7 @@ class LatticeArena:
         self._xfer.h2d_bytes = 0
         self._xfer.d2h_bytes = 0
         self._xfer.device_syncs = 0
+        self._xfer.sync_s = 0.0
 
     # -- plumbing -------------------------------------------------------------
     @staticmethod
@@ -1015,8 +1025,10 @@ class LatticeArena:
 
         from ..kernels import ops
 
-        flat, clock, rank = jax.device_get(
-            ops.slab_row(slab.vals, slab.clocks, slab.nodes, row))
+        row_planes = ops.slab_row(slab.vals, slab.clocks, slab.nodes, row)
+        t0 = time.perf_counter()
+        flat, clock, rank = jax.device_get(row_planes)
+        slab.xfer.sync_s += time.perf_counter() - t0
         slab.xfer.device_syncs += 1
         slab.xfer.d2h_bytes += flat.nbytes + 8
         return int(clock), int(rank), np.asarray(flat)
@@ -1280,6 +1292,10 @@ class MergeEngine:
     def device_syncs(self) -> int:
         return self.arena.device_syncs
 
+    @property
+    def device_sync_s(self) -> float:
+        return self.arena.device_sync_s
+
     def reset_transfer_stats(self) -> None:
         self.arena.reset_transfer_stats()
 
@@ -1526,7 +1542,9 @@ class MergeEngine:
             self.plane_object_fallbacks += len(bad)
             bad_pg = pg.take(bad)
             if bad_pg.is_device():  # the exact path is host-side: one sync
+                t0 = time.perf_counter()
                 bad_pg = bad_pg.to_host()
+                self.arena._xfer.sync_s += time.perf_counter() - t0
                 self.arena._xfer.device_syncs += 1
                 self.arena._xfer.d2h_bytes += bad_pg.vals.nbytes
             for i, key in enumerate(bad_pg.keys):

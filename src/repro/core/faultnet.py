@@ -413,12 +413,6 @@ class FailureDetector:
                 if alive_fn():
                     self._m_false.inc()
 
-    def staleness(self, endpoints) -> float:
-        """Seconds since the most stale of ``endpoints`` was heard."""
-        now = self.clock.now
-        heard = [self.last_heard.get(e, now) for e in endpoints]
-        return max((now - h for h in heard), default=0.0)
-
 
 class FailurePlane:
     """Bundles the shared clock, fault network, detector and retry

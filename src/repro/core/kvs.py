@@ -38,7 +38,11 @@ from .faultnet import FailurePlane, KVSUnavailableError, RetryPolicy
 from .lattices import Lattice
 from .netsim import NetworkProfile, VirtualClock, DEFAULT_PROFILE
 from .remesh import PlaneMover
-from ..obs import MetricsRegistry, NULL_TRACER, Tracer, counter_shim
+from ..obs import MetricsRegistry, Tracer, counter_shim
+
+# the host<->device transfer ledger of every engine (``transfer_stats``),
+# each also a registry callback ``kvs.<field>``
+_XFER_FIELDS = ("h2d_bytes", "d2h_bytes", "device_syncs", "device_sync_s")
 
 
 def _hash(s: str) -> int:
@@ -104,10 +108,12 @@ class AnnaKVS:
         self.replication = replication
         self.sync_replication = sync_replication
         # observability plane: a Cluster passes its shared registry and
-        # tracer; a standalone KVS gets its own registry and the shared
-        # disabled tracer (spans only record under a traced DAG run)
+        # tracer; a standalone KVS gets its own registry and a disabled
+        # tracer counting its phases there (spans only record under a
+        # traced DAG run)
         self.metrics = metrics if metrics is not None else MetricsRegistry()
-        self.tracer = tracer if tracer is not None else NULL_TRACER
+        self.tracer = (tracer if tracer is not None
+                       else Tracer()).bind(self.metrics)
         # device-resident slab tier: arena planes live as donated jax
         # arrays on every storage node (None → REPRO_DEVICE_TIER env)
         self.device_tier = (device_tier_default() if device_tier is None
@@ -152,7 +158,10 @@ class AnnaKVS:
         self._m_retries = self.metrics.counter("kvs.retries")
         self._m_backoff = self.metrics.counter("kvs.backoff_s")
         self._m_degraded = self.metrics.counter("kvs.degraded_reads")
-        self._m_staleness = self.metrics.gauge("kvs.staleness_s")
+        # get_merged_many's read-plan memo: a hit re-executes a cached
+        # plan, a miss walks the ring and builds one
+        self._m_plan_hits = self.metrics.counter("kvs.read_plan.hits")
+        self._m_plan_misses = self.metrics.counter("kvs.read_plan.misses")
         # pull-based telemetry: the plane counters mutate inside kernel
         # launch paths, so the registry reads them lazily at snapshot —
         # zero added cost on the hot planes
@@ -163,7 +172,7 @@ class AnnaKVS:
         self.metrics.register_callback(
             "kvs.reader.plane_object_fallbacks",
             lambda: self.reader.plane_object_fallbacks)
-        for field in ("h2d_bytes", "d2h_bytes", "device_syncs"):
+        for field in _XFER_FIELDS:
             self.metrics.register_callback(
                 f"kvs.{field}",
                 lambda f=field: self.transfer_stats()[f],
@@ -502,7 +511,9 @@ class AnnaKVS:
         block for durability); the default async path acks after the
         coordinator and gossips the rest (cache flush path)."""
         sync = self.sync_replication if sync is None else sync
-        merge_targets, gossip_targets = self._route_put(key, value, sync, clock)
+        with self.tracer.phase("kvs.route"):
+            merge_targets, gossip_targets = self._route_put(
+                key, value, sync, clock)
         merged: Optional[Lattice] = None
         for owner in merge_targets:
             merged = self.nodes[owner].merge_in(key, value)
@@ -538,28 +549,28 @@ class AnnaKVS:
             sp = tr.start("kvs", "put_many", clock=clock or tr.cur.clock,
                           tid=tr.cur.tid, parent=tr.cur, n_items=len(items))
         coord_batches: Dict[str, List[Tuple[str, Lattice]]] = defaultdict(list)
-
-        def apply_batches() -> None:
-            for owner, batch in coord_batches.items():
-                self.nodes[owner].engine.merge_batch(batch)
-
-        for key, value in items:
-            try:
-                merge_targets, gossip_targets = self._route_put(
-                    key, value, sync, clock)
-            except RuntimeError:
-                apply_batches()
-                raise
-            for owner in merge_targets:
-                coord_batches[owner].append((key, value))
-            if self.faultnet is None:
-                for owner in gossip_targets:
-                    self.nodes[owner].inbox.add(key, value)
-            else:
-                for owner in gossip_targets:
-                    self.faultnet.deliver("gossip", merge_targets[0], owner,
-                                          key=key, value=value)
-        apply_batches()
+        unrouted: Optional[RuntimeError] = None
+        with tr.phase("kvs.route"):
+            for key, value in items:
+                try:
+                    merge_targets, gossip_targets = self._route_put(
+                        key, value, sync, clock)
+                except RuntimeError as e:
+                    unrouted = e
+                    break
+                for owner in merge_targets:
+                    coord_batches[owner].append((key, value))
+                if self.faultnet is None:
+                    for owner in gossip_targets:
+                        self.nodes[owner].inbox.add(key, value)
+                else:
+                    for owner in gossip_targets:
+                        self.faultnet.deliver("gossip", merge_targets[0],
+                                              owner, key=key, value=value)
+        for owner, batch in coord_batches.items():
+            self.nodes[owner].engine.merge_batch(batch)
+        if unrouted is not None:
+            raise unrouted
         if sp is not None:
             tr.finish(sp)
         return len(items)
@@ -763,14 +774,6 @@ class AnnaKVS:
                 result = val if result is None else result.merge(val)
         return result
 
-    def _record_degraded(self, n_keys: int, unreachable) -> None:
-        """Account a read served from fewer replicas than placement
-        says: bump ``kvs.degraded_reads`` and publish how stale the
-        missing replicas might be (time since last heard)."""
-        self._m_degraded.inc(n_keys)
-        if self.detector is not None and unreachable:
-            self._m_staleness.set(self.detector.staleness(unreachable))
-
     def get_merged(self, key: str, clock: Optional[VirtualClock] = None,
                    allow_partial: bool = True) -> Optional[Lattice]:
         """Read-repair style read: merge across all reachable replicas.
@@ -795,7 +798,7 @@ class AnnaKVS:
             if unreachable:
                 if len(unreachable) == len(owners) or not allow_partial:
                     raise KVSUnavailableError([key], op="get_merged")
-                self._record_degraded(1, unreachable)
+                self._m_degraded.inc()
         result = self._merge_replicas(key)
         if clock is not None:
             size = result.byte_size() if result is not None else 0
@@ -856,7 +859,7 @@ class AnnaKVS:
                 degraded += 1  # no reachable replica: key absent, the
                 # cache falls back to its local copy
         if degraded:
-            self._record_degraded(degraded, ())
+            self._m_degraded.inc(degraded)
         batch, leftover = self.reader.reduce_replica_planes(
             [(key, (node.engine,)) for key, node in chosen])
         by_key = dict(chosen)
@@ -919,7 +922,6 @@ class AnnaKVS:
                        for nid, n in self.nodes.items()):
                 unavailable: List[str] = []
                 partial = 0
-                stale_owners: Set[str] = set()
                 for key in ukeys:
                     owners = self._owners(key)
                     down = [o for o in owners
@@ -930,7 +932,6 @@ class AnnaKVS:
                         unavailable.append(key)
                     else:
                         partial += 1
-                    stale_owners.update(down)
                 if unavailable:
                     if on_unavailable == "raise" or not allow_partial:
                         raise KVSUnavailableError(
@@ -939,7 +940,7 @@ class AnnaKVS:
                                   set(unavailable))
                     partial += len(unavailable)
                 if partial:
-                    self._record_degraded(partial, stale_owners)
+                    self._m_degraded.inc(partial)
         sig = (self._placement_epoch,
                tuple((nid, self._reachable(nid, node),
                       node.engine.layout_version)
@@ -947,7 +948,9 @@ class AnnaKVS:
         cached = self._read_plans.get(ukeys)
         if cached is not None and cached[0] == sig:
             plan = cached[1]
+            self._m_plan_hits.inc()
         else:
+            self._m_plan_misses.inc()
             live = {nid: node.engine for nid, node in self.nodes.items()
                     if self._reachable(nid, node)}
             keyed = [
@@ -1087,22 +1090,15 @@ class AnnaKVS:
         R-replica read-reduction engine) so regressions localize to the
         engine that caused them.  :meth:`reset_transfer_stats` windows
         measurements without rebuilding the tier."""
+        engines = {nid: n.engine for nid, n in self.nodes.items()}
+        engines["reader"] = self.reader
         per_engine = {
-            nid: {
-                "h2d_bytes": n.engine.h2d_bytes,
-                "d2h_bytes": n.engine.d2h_bytes,
-                "device_syncs": n.engine.device_syncs,
-            }
-            for nid, n in self.nodes.items()
-        }
-        per_engine["reader"] = {
-            "h2d_bytes": self.reader.h2d_bytes,
-            "d2h_bytes": self.reader.d2h_bytes,
-            "device_syncs": self.reader.device_syncs,
+            name: {field: getattr(engine, field) for field in _XFER_FIELDS}
+            for name, engine in engines.items()
         }
         out: Dict[str, object] = {
             field: sum(stats[field] for stats in per_engine.values())
-            for field in ("h2d_bytes", "d2h_bytes", "device_syncs")
+            for field in _XFER_FIELDS
         }
         out["per_engine"] = per_engine
         return out

@@ -66,6 +66,7 @@ from .lattices import LamportClock, Lattice, LWWLattice, encapsulate
 from .netsim import NetworkProfile, VirtualClock
 from .scheduler import Scheduler, SchedulingPolicy
 from ..obs import MetricsRegistry, Tracer, counter_shim
+from ..obs import host as obs_host
 from ..obs.trace import Span
 
 
@@ -107,6 +108,11 @@ class DagRun:
     clock: VirtualClock
     response_key: Optional[str] = None
     t0: float = 0.0
+    # host wall clock (perf_counter) at submit, and whether a trigger of
+    # the run has been dispatched yet: the engine.queue / engine.run_wall
+    # counters measure from here
+    wall0: float = 0.0
+    dispatched: bool = False
     # -- per-attempt state --------------------------------------------------
     session: Optional[SessionContext] = None
     schedule: Dict[str, str] = dataclasses.field(default_factory=dict)
@@ -275,7 +281,9 @@ class Cluster:
         # are shared with the KVS tier, every cache and the scheduler
         # (env default: REPRO_TRACE / REPRO_TRACE_SAMPLE)
         self.metrics = metrics if metrics is not None else MetricsRegistry()
-        self.tracer = tracer if tracer is not None else Tracer.from_env()
+        self.tracer = (tracer if tracer is not None
+                       else Tracer.from_env()).bind(self.metrics)
+        obs_host.register(self.metrics)
         self.kvs = AnnaKVS(
             num_nodes=n_kvs_nodes, replication=replication,
             profile=self.profile, metrics=self.metrics, tracer=self.tracer,
@@ -319,6 +327,12 @@ class Cluster:
         self._m_failed = m.counter("engine.runs_failed")
         self._m_restarts = m.counter("engine.run_restarts")
         self._m_run_latency = m.histogram("engine.run_latency_s")
+        # host wall seconds from submit to the first dispatch, and from
+        # submit to finalize (completed runs)
+        self._m_queue_s = m.counter("engine.queue.s")
+        self._m_queue_n = m.counter("engine.queue.n")
+        self._m_run_wall_s = m.counter("engine.run_wall.s")
+        self._m_run_wall_n = m.counter("engine.run_wall.n")
         # cross-request model batching: waves of same-function triggers
         # dispatched through the pinned callable's ``batch_call`` hook
         self._m_batched_invokes = m.counter("engine.batched_invokes")
@@ -559,6 +573,7 @@ class Cluster:
             response_key=response_key,
         )
         run.t0 = run.clock.now
+        run.wall0 = time.perf_counter()
         if self.tracer.sample_run():
             # root span on the run's own virtual timeline: closed at
             # finalize, so duration == DagResult.latency exactly
@@ -632,97 +647,117 @@ class Cluster:
             return 0
         self.engine_turns += 1
         tr = self.tracer
-        # one engine-turn span on the tracer's WALL timeline (a turn
-        # serves many runs, so no single virtual clock applies); opened
-        # only when at least one sampled run participates, and set as
-        # the active context so cross-run infrastructure spans (batched
-        # scheduling, fused plane launches) attach under it
-        turn_span = None
-        if tr.enabled and any(r.span is not None for r, _f, _a, _t in triggers):
-            turn_span = tr.start("engine", "step", tid="engine",
-                                 turn=self.engine_turns,
-                                 n_triggers=len(triggers))
-        with tr.use(turn_span):
-            # batched scheduling: one entry point call for the whole wave.
-            # If it raises (a trigger with no schedulable executor, a buggy
-            # custom policy), fall back to per-trigger picks so ONLY the
-            # offending runs fail — exclude sets are per-run, so one run's
-            # unschedulable trigger must not kill the healthy wave.
-            trigger_specs = [(fn, run.args_by_fn.get(fn, ()), run.exclude)
-                             for run, fn, _args, _att in triggers]
-            try:
-                picks: List[Optional[str]] = list(
-                    self.scheduler.schedule_ready(trigger_specs))
-            except Exception:
-                picks = []
-                for (run, fn, _args, attempt), spec in zip(triggers,
-                                                           trigger_specs):
-                    try:
-                        picks.append(self.scheduler.pick_executor(
-                            spec[0], spec[1], exclude=spec[2]))
-                    except Exception as e:
-                        picks.append(None)
-                        if run.state == RUN_RUNNING and run.attempt == attempt:
-                            self._fail_user(run, e)  # propagate as-is, no retry
-            plans: List[Tuple[DagRun, str, Tuple[Any, ...], str, int]] = []
-            for (run, fn, args, attempt), eid in zip(triggers, picks):
-                if eid is None:
-                    continue
-                run.schedule[fn] = eid
-                executor = self.executors[eid]
-                t_dispatch = run.clock.now
-                # executor->executor trigger carries session metadata (§5.3)
-                meta_bytes = run.session.metadata_bytes() + 256
-                run.clock.advance(self.profile.sample(self.profile.tcp, meta_bytes))
-                if not executor.has_function(fn):
-                    # cold executor: pull + deserialize the function from Anna
-                    try:
-                        executor.pin_function(fn, self.scheduler.load_function(fn))
-                    except Exception as e:  # function vanished from the KVS
-                        self._fail_user(run, e)
-                        continue
-                    run.clock.advance(self.profile.sample(self.profile.kvs_op, 1024))
-                plans.append((run, fn, args, eid, attempt))
-                if run.span is not None:
-                    # trigger-hop + cold-pin window on the run's timeline
-                    tr.add_complete("scheduler", f"dispatch.{fn}", t_dispatch,
-                                    run.clock.now, tid=run.run_id,
-                                    parent=run.span, executor=eid)
+        # the turn's phases count on every turn; the engine.step span is
+        # also recorded on the tracer's WALL timeline (a turn serves many
+        # runs, so no single virtual clock applies) when at least one
+        # sampled run participates, and is the active context so
+        # cross-run infrastructure spans (batched scheduling, fused plane
+        # launches) attach under it
+        sampled = tr.enabled and any(r.span is not None
+                                     for r, _f, _a, _t in triggers)
+        with tr.phase("engine.step", record=sampled,
+                      turn=self.engine_turns, n_triggers=len(triggers)):
+            with tr.phase("engine.schedule"):
+                plans = self._schedule(triggers)
             if self.read_prefetch:
-                self._fused_prefetch(plans)
-            # cross-request model batching: a wave's same-function
-            # triggers landing on the SAME cache (VM) whose pinned
-            # callable exposes ``batch_call`` dispatch as ONE user-code
-            # call — the continuous-batching serving path.  Batched
-            # groups go first, then the leftover singles in original
-            # plan order, so a wave with nothing batchable replays the
-            # sequential invocation (and rng draw) order exactly.
-            groups: Dict[Tuple[str, str], List[
-                Tuple[DagRun, str, Tuple[Any, ...], str, int]]] = {}
-            for plan in plans:
-                _run, fn, _args, eid, _att = plan
-                func = self.executors[eid].pinned.get(fn)
-                if callable(getattr(func, "batch_call", None)):
-                    key = (fn, self.executors[eid].cache.cache_id)
-                    groups.setdefault(key, []).append(plan)
-            batched_ids: Set[int] = set()
-            for group in groups.values():
-                if len(group) < 2:
-                    continue
-                batched_ids.update(id(p) for p in group)
-                self._invoke_batched(group)
-            for plan in plans:
-                if id(plan) in batched_ids:
-                    continue
-                run, fn, args, eid, attempt = plan
-                # skip triggers whose run restarted/failed earlier this turn
-                if run.state != RUN_RUNNING or run.attempt != attempt:
-                    continue
-                self._invoke_trigger(run, fn, args, eid)
-            self._finalize_completed()
-        if turn_span is not None:
-            tr.finish(turn_span)
+                with tr.phase("engine.prefetch"):
+                    self._fused_prefetch(plans)
+            with tr.phase("engine.invoke"):
+                self._invoke_wave(plans)
+            with tr.phase("engine.finalize"):
+                self._finalize_completed()
         return len(triggers)
+
+    def _schedule(
+        self, triggers: List[Tuple[DagRun, str, Tuple[Any, ...], int]],
+    ) -> List[Tuple[DagRun, str, Tuple[Any, ...], str, int]]:
+        """Pick an executor for every trigger of the wave (ONE batched
+        ``schedule_ready`` call), charge the trigger hop and cold pins;
+        returns the dispatch plans."""
+        tr = self.tracer
+        # batched scheduling: one entry point call for the whole wave.
+        # If it raises (a trigger with no schedulable executor, a buggy
+        # custom policy), fall back to per-trigger picks so ONLY the
+        # offending runs fail — exclude sets are per-run, so one run's
+        # unschedulable trigger must not kill the healthy wave.
+        trigger_specs = [(fn, run.args_by_fn.get(fn, ()), run.exclude)
+                         for run, fn, _args, _att in triggers]
+        try:
+            picks: List[Optional[str]] = list(
+                self.scheduler.schedule_ready(trigger_specs))
+        except Exception:
+            picks = []
+            for (run, fn, _args, attempt), spec in zip(triggers,
+                                                       trigger_specs):
+                try:
+                    picks.append(self.scheduler.pick_executor(
+                        spec[0], spec[1], exclude=spec[2]))
+                except Exception as e:
+                    picks.append(None)
+                    if run.state == RUN_RUNNING and run.attempt == attempt:
+                        self._fail_user(run, e)  # propagate as-is, no retry
+        plans: List[Tuple[DagRun, str, Tuple[Any, ...], str, int]] = []
+        for (run, fn, args, attempt), eid in zip(triggers, picks):
+            if eid is None:
+                continue
+            run.schedule[fn] = eid
+            executor = self.executors[eid]
+            t_dispatch = run.clock.now
+            # executor->executor trigger carries session metadata (§5.3)
+            meta_bytes = run.session.metadata_bytes() + 256
+            run.clock.advance(self.profile.sample(self.profile.tcp, meta_bytes))
+            if not executor.has_function(fn):
+                # cold executor: pull + deserialize the function from Anna
+                try:
+                    executor.pin_function(fn, self.scheduler.load_function(fn))
+                except Exception as e:  # function vanished from the KVS
+                    self._fail_user(run, e)
+                    continue
+                run.clock.advance(self.profile.sample(self.profile.kvs_op, 1024))
+            plans.append((run, fn, args, eid, attempt))
+            if not run.dispatched:
+                run.dispatched = True
+                self._m_queue_s.inc(time.perf_counter() - run.wall0)
+                self._m_queue_n.inc()
+            if run.span is not None:
+                # trigger-hop + cold-pin window on the run's timeline
+                tr.add_complete("scheduler", f"dispatch.{fn}", t_dispatch,
+                                run.clock.now, tid=run.run_id,
+                                parent=run.span, executor=eid)
+        return plans
+
+    def _invoke_wave(
+        self, plans: Sequence[Tuple[DagRun, str, Tuple[Any, ...], str, int]]
+    ) -> None:
+        """Invoke the wave's plans.  Cross-request model batching: a
+        wave's same-function triggers landing on the SAME cache (VM)
+        whose pinned callable exposes ``batch_call`` dispatch as ONE
+        user-code call — the continuous-batching serving path.  Batched
+        groups go first, then the leftover singles in original plan
+        order, so a wave with nothing batchable replays the sequential
+        invocation (and rng draw) order exactly."""
+        groups: Dict[Tuple[str, str], List[
+            Tuple[DagRun, str, Tuple[Any, ...], str, int]]] = {}
+        for plan in plans:
+            _run, fn, _args, eid, _att = plan
+            func = self.executors[eid].pinned.get(fn)
+            if callable(getattr(func, "batch_call", None)):
+                key = (fn, self.executors[eid].cache.cache_id)
+                groups.setdefault(key, []).append(plan)
+        batched_ids: Set[int] = set()
+        for group in groups.values():
+            if len(group) < 2:
+                continue
+            batched_ids.update(id(p) for p in group)
+            self._invoke_batched(group)
+        for plan in plans:
+            if id(plan) in batched_ids:
+                continue
+            run, fn, args, eid, attempt = plan
+            # skip triggers whose run restarted/failed earlier this turn
+            if run.state != RUN_RUNNING or run.attempt != attempt:
+                continue
+            self._invoke_trigger(run, fn, args, eid)
 
     def _fused_prefetch(
         self, plans: Sequence[Tuple[DagRun, str, Tuple[Any, ...], str, int]]
@@ -1129,6 +1164,8 @@ class Cluster:
             )
             self._m_completed.inc()
             self._m_run_latency.observe(run.result.latency)
+            self._m_run_wall_s.inc(time.perf_counter() - run.wall0)
+            self._m_run_wall_n.inc()
             if run.span is not None:
                 # root closes at the SAME virtual instant the latency is
                 # computed from: span.duration == DagResult.latency
@@ -1238,12 +1275,18 @@ class Cluster:
         # With many DAGs in flight, one cache flush carries ALL their
         # pending write-backs in one put_many / PlaneBatch.
         p = self.tick_jitter if defer_prob is None else defer_prob
-        self.kvs.tick(p)
-        for cache in self.caches.values():
-            cache.tick(defer_prob=p)
-        for cache in self.caches.values():
-            cache.publish_keyset()
-        self.scheduler.refresh_index()
+        tr = self.tracer
+        with tr.phase("engine.tick"):
+            with tr.phase("kvs.gossip"):
+                self.kvs.tick(p)
+            for cache in self.caches.values():
+                with tr.phase("cache.flush"):
+                    cache.tick(defer_prob=p)
+            for cache in self.caches.values():
+                with tr.phase("sched.keyset"):
+                    cache.publish_keyset()
+            with tr.phase("sched.index"):
+                self.scheduler.refresh_index()
 
     # -- fault injection -----------------------------------------------------------------
     def fail_vm(self, vm_id: str) -> None:

@@ -12,7 +12,12 @@ benchmarks' tail-latency reporting:
   ``Cluster.step`` → ``Scheduler.schedule_ready`` → executor invoke →
   ``ExecutorCache.read_many`` → ``AnnaKVS`` plane launches, carrying
   each run's virtual clock; exports JSONL and Chrome ``trace_event``
-  format (load in chrome://tracing / https://ui.perfetto.dev).
+  format (load in chrome://tracing / https://ui.perfetto.dev); and
+  ``Tracer.phase``, the always-on wall-clock phases at the layer
+  boundaries, counted in the registry and annotated (``cb.<name>``)
+  into a ``jax.profiler`` trace.
+* :mod:`repro.obs.host` — process-wide collector pauses and JAX
+  compiles (``host.gc.*``, ``host.jit.*``).
 """
 
 from .metrics import (

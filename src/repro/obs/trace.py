@@ -28,6 +28,14 @@ Export: :meth:`Tracer.export_jsonl` (one span per line) and
 file in chrome://tracing or https://ui.perfetto.dev; each ``tid`` row
 is one timeline: the engine's wall track plus one track per traced
 run).
+
+Phases (:meth:`Tracer.phase`) are the always-on wall-clock spans at
+the layer boundaries (``engine.step``, ``engine.tick``, ``kvs.route``,
+...).  Each adds its host seconds and a count to the registry counters
+``<name>.s`` / ``<name>.n``, and while a ``jax.profiler`` session is
+capturing it records a ``cb.<name>`` annotation into the same trace as
+the device ops, on the same clock.  Phases nest strictly, so a layer's
+self time is its phase minus its children's.
 """
 
 from __future__ import annotations
@@ -37,7 +45,25 @@ import os
 import time
 from typing import Any, Dict, List, Optional
 
-__all__ = ["NULL_TRACER", "Span", "Tracer"]
+from .metrics import MetricsRegistry
+
+__all__ = ["NULL_TRACER", "Span", "Tracer", "profiler_annotation"]
+
+_ANNOTATION: Any = None  # jax.profiler.TraceAnnotation; False sans jax
+
+
+def profiler_annotation():
+    """``jax.profiler.TraceAnnotation``, or None where JAX is absent
+    (imported on first use, so ``repro.obs`` imports without JAX).  Its
+    ``is_enabled()`` says whether a profiler session is capturing."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = False
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION or None
 
 
 class Span:
@@ -136,6 +162,58 @@ class _UseCM:
         return False
 
 
+class _Phase:
+    """A named phase's registry counters and profiler label."""
+
+    __slots__ = ("name", "cat", "label", "s", "n")
+
+    def __init__(self, name: str, metrics: MetricsRegistry):
+        self.name = name
+        self.cat = name.split(".", 1)[0]
+        self.label = f"cb.{name}"
+        self.s = metrics.counter(f"{name}.s")
+        self.n = metrics.counter(f"{name}.n")
+
+
+class _PhaseCM:
+    __slots__ = ("tr", "ph", "record", "attrs", "t0", "ann", "span",
+                 "prev")
+
+    def __init__(self, tr: "Tracer", ph: _Phase, record: bool,
+                 attrs: Dict[str, Any]):
+        self.tr = tr
+        self.ph = ph
+        self.record = record
+        self.attrs = attrs
+        self.ann = None
+        self.span: Optional[Span] = None
+
+    def __enter__(self) -> Optional[Span]:
+        ann = profiler_annotation()
+        if ann is not None and ann.is_enabled():
+            self.ann = ann(self.ph.label)
+            self.ann.__enter__()
+        if self.record:
+            tr = self.tr
+            self.prev = tr.cur
+            self.span = tr.start(self.ph.cat, self.ph.name, tid="engine",
+                                 parent=tr.cur, **self.attrs)
+            tr.cur = self.span
+        self.t0 = time.perf_counter()
+        return self.span
+
+    def __exit__(self, *exc):
+        ph = self.ph
+        ph.s.value += time.perf_counter() - self.t0
+        ph.n.value += 1
+        if self.span is not None:
+            self.tr.finish(self.span)
+            self.tr.cur = self.prev
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
+        return False
+
+
 class Tracer:
     """Span recorder; one per deployment (the cluster shares it with
     the KVS, the scheduler and every cache)."""
@@ -157,6 +235,17 @@ class Tracer:
         # record nothing when it is None)
         self.cur: Optional[Span] = None
         self._t0_wall = time.perf_counter()
+        # where phases count: the deployment's registry once bound
+        self.metrics = MetricsRegistry()
+        self._phases: Dict[str, _Phase] = {}
+
+    def bind(self, metrics: MetricsRegistry) -> "Tracer":
+        """Count phases into ``metrics``, the deployment's shared
+        registry."""
+        if metrics is not self.metrics:
+            self.metrics = metrics
+            self._phases = {}
+        return self
 
     @classmethod
     def from_env(cls) -> "Tracer":
@@ -226,6 +315,23 @@ class Tracer:
             tid = cur.tid
         sp = self.start(cat, name, clock=clock, tid=tid, parent=cur, **attrs)
         return _SpanCM(self, sp)
+
+    def phase(self, name: str, record: Optional[bool] = None,
+              **attrs: Any) -> _PhaseCM:
+        """Context manager for one always-on wall-clock phase: adds its
+        host seconds and a count to ``<name>.s`` / ``<name>.n`` in the
+        bound registry and opens a ``cb.<name>`` profiler annotation
+        while a profiler session captures.  It also records a
+        :class:`Span` on the ``"engine"`` wall timeline, as the active
+        context for the spans inside it, when the tracer is enabled and
+        ``record`` says so (default: a traced context on the wall
+        timeline is active, so per-run virtual timelines stay pure)."""
+        ph = self._phases.get(name)
+        if ph is None:
+            ph = self._phases[name] = _Phase(name, self.metrics)
+        if record is None:
+            record = self.cur is not None and self.cur.clock is None
+        return _PhaseCM(self, ph, self.enabled and record, attrs)
 
     def use(self, span: Optional[Span]):
         """Parent subsequent infrastructure spans under ``span`` for the

@@ -59,8 +59,7 @@ import importlib.util
 spec = importlib.util.spec_from_file_location("chip_smoke", "chip_smoke.py")
 mod = importlib.util.module_from_spec(spec)
 spec.loader.exec_module(mod)
-mod.sharded_store_phase(0, 1 << 10, mod.CompileLog(), n_dag_calls=32,
-                        n_causal=8, chunk=256)
+mod.sharded_store_phase(0, 1 << 10, n_dag_calls=32, n_causal=8, chunk=256)
 """
 
 

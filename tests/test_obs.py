@@ -365,3 +365,149 @@ def test_disabled_tracer_records_nothing_on_hot_paths():
     c.call_dag("diamond",
                {"a": (CloudburstReference("k1"), CloudburstReference("k2"))})
     assert tr.spans == [] and tr.dropped == 0
+
+
+# ---------------------------------------------------------------------------
+# phases, host signals and the counters beside the work
+# ---------------------------------------------------------------------------
+
+_TURN_PHASES = ("engine.schedule", "engine.prefetch", "engine.invoke",
+                "engine.finalize")
+_TICK_PHASES = ("kvs.gossip", "cache.flush", "sched.keyset", "sched.index")
+
+
+def test_phases_count_turns_and_ticks_and_nest():
+    c = Cluster(n_vms=2, executors_per_vm=2, n_kvs_nodes=2, seed=0)
+    c.register(lambda x: float(np.sum(np.asarray(x))), "a")
+    c.register(lambda v: v + 1.0, "b")
+    c.register_dag("ab", ["a", "b"], edges=[("a", "b")])
+    for i in range(6):
+        c.put(f"k{i}", np.full(16, i, np.float32))
+    c.reset_telemetry()
+    turns = ticks = 0
+    futures = []
+    for wave in range(3):
+        futures += [c.call_dag_async("ab", {"a": (
+            CloudburstReference(f"k{(wave + j) % 6}"),)}) for j in range(4)]
+        while any(not f.done() for f in futures):
+            turns += c.step() > 0
+            c.tick()
+            ticks += 1
+    assert c.step() == 0  # a turn with no triggers opens no phase
+    snap = c.telemetry()
+    assert snap["engine.step.n"] == turns == snap["engine.turns"]
+    assert snap["engine.tick.n"] == ticks
+    assert snap["cache.flush.n"] == ticks * len(c.caches)
+    assert snap["sched.keyset.n"] == ticks * len(c.caches)
+    for parent, children in (("engine.step", _TURN_PHASES),
+                             ("engine.tick", _TICK_PHASES)):
+        for child in children:
+            assert 0 < snap[f"{child}.s"] <= snap[f"{parent}.s"]
+        assert sum(snap[f"{ch}.s"] for ch in children) <= snap[f"{parent}.s"]
+    # routing runs inside the cache flushes and the response writes only
+    assert 0 < snap["kvs.route.s"] <= (snap["cache.flush.s"]
+                                       + snap["engine.finalize.s"])
+    # one queue wait per run (first dispatch), one wall span per completion
+    assert snap["engine.queue.n"] == snap["engine.runs_submitted"] == 12
+    assert snap["engine.run_wall.n"] == snap["engine.runs_completed"] == 12
+    assert 0 < snap["engine.queue.s"] < snap["engine.run_wall.s"]
+
+
+def test_phase_spans_nest_on_the_engine_timeline():
+    tr = Tracer(enabled=True)
+    c = _diamond_cluster(tr)
+    c.call_dag("diamond",
+               {"a": (CloudburstReference("k1"), CloudburstReference("k2"))})
+    steps = {s.sid: s for s in tr.spans if s.name == "engine.step"}
+    assert len(steps) == 3  # a, {b, c}, d: one turn each
+    assert all(s.tid == "engine" and s.cat == "engine" for s in steps.values())
+    assert [s.attrs["turn"] for s in steps.values()] == [1, 2, 3]
+    children = [s for s in tr.spans if s.name in _TURN_PHASES]
+    assert len(children) == 4 * len(steps)
+    for s in children:
+        parent = steps[s.parent]
+        assert parent.t0 <= s.t0 <= s.t1 <= parent.t1
+    # a disabled tracer still counts every phase, and records no span
+    off = Tracer(enabled=False)
+    c = _diamond_cluster(off)
+    c.call_dag("diamond",
+               {"a": (CloudburstReference("k1"), CloudburstReference("k2"))})
+    assert off.spans == [] and c.telemetry()["engine.step.n"] == 3
+
+
+def test_collector_hook_is_installed_once_and_counts_pauses():
+    import gc
+
+    from repro.obs import host as obs_host
+
+    for _ in range(3):
+        Cluster(n_vms=1, executors_per_vm=1, n_kvs_nodes=1)
+    assert sum(cb is obs_host._on_gc for cb in gc.callbacks) == 1
+    c = Cluster(n_vms=1, executors_per_vm=1, n_kvs_nodes=1)
+    was_enabled = gc.isenabled()
+    gc.disable()  # only the collection below runs between the reads
+    try:
+        before = c.telemetry()
+        gc.collect(2)
+        after = c.telemetry()
+    finally:
+        if was_enabled:
+            gc.enable()
+    assert (after["host.gc.collections.gen2"]
+            - before["host.gc.collections.gen2"]) == 1
+    assert after["host.gc.pause_s"] > before["host.gc.pause_s"]
+    assert after["host.gc.gen2.pause_s"] > before["host.gc.gen2.pause_s"]
+    assert after["host.gc.collections.gen0"] == before[
+        "host.gc.collections.gen0"]
+
+
+def test_jit_listener_counts_backend_compiles():
+    import jax
+    import jax.numpy as jnp
+
+    from repro.obs import host as obs_host
+
+    obs_host.install()
+    before = obs_host.snapshot()
+    width = 7 + before["host.jit.compiles"] % 5  # a shape not built before
+    jax.jit(lambda x: x * 3 + 1)(jnp.ones((width, 3))).block_until_ready()
+    after = obs_host.snapshot()
+    assert after["host.jit.compiles"] > before["host.jit.compiles"]
+    assert after["host.jit.compile_s"] > before["host.jit.compile_s"]
+
+
+def test_read_plan_memo_counts_one_miss_then_hits():
+    from repro.core.lattices import LWWLattice
+
+    kvs = AnnaKVS(num_nodes=3, replication=2)
+    keys = [f"k{i}" for i in range(5)]
+    for i, key in enumerate(keys):
+        kvs.put(key, LWWLattice((1, "w"), np.full(8, i, np.float32)),
+                sync=True)
+    for _ in range(3):
+        kvs.get_merged_many(keys)
+    snap = kvs.metrics.snapshot()
+    assert snap["kvs.read_plan.misses"] == 1
+    assert snap["kvs.read_plan.hits"] == 2
+    # a standalone KVS counts its own routing phase
+    assert snap["kvs.route.n"] == len(keys)
+
+
+def test_device_sync_seconds_sit_beside_the_sync_count():
+    from repro.core.lattices import LWWLattice
+
+    kvs = AnnaKVS(num_nodes=2, replication=2, device_tier=True)
+    keys = [f"k{i}" for i in range(4)]
+    kvs.put_many([(k, LWWLattice((1, "w"), np.full(128, i, np.float32)))
+                  for i, k in enumerate(keys)], sync=True)
+    kvs.reset_transfer_stats()
+    batch = kvs.get_merged_many(keys)
+    assert all(pg.is_device() for pg in batch.groups.values())
+    batch.to_host(kvs.reader.arena._xfer)
+    stats = kvs.transfer_stats()
+    assert stats["device_syncs"] == len(batch.groups) >= 1
+    assert stats["device_sync_s"] > 0
+    assert kvs.metrics.snapshot()["kvs.device_sync_s"] == stats[
+        "device_sync_s"]
+    kvs.reset_transfer_stats()
+    assert kvs.transfer_stats()["device_sync_s"] == 0
