@@ -59,6 +59,9 @@ Architecture
     counters (``plane_keys``, ``plane_object_fallbacks``,
     ``arena.materializations``) let tests assert that steady-state
     replication constructs zero per-key lattice objects.
+    ``watch_keys()`` returns a :class:`KeyWatch` that collects the net
+    keys added to and removed from the engine (arena and fallback) until
+    its owner drains it; an engine nobody watches records nothing.
 
 Vector-clock helpers (``vc_classify_batch`` and friends) densify
 ``VectorClock`` pairs into ``(K, N)`` int32 matrices and classify
@@ -80,7 +83,7 @@ unchanged when the mesh has one device.
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 
 import math
 import os
@@ -240,6 +243,37 @@ def _concat(parts: Sequence[Any]):
 
         return jnp.concatenate(list(parts))
     return np.concatenate(list(parts))
+
+
+class KeyWatch:
+    """Net membership delta of one :class:`MergeEngine` since the last
+    :meth:`drain`: keys that became members (``added``) and keys that
+    stopped being members (``removed``).  The two sets stay disjoint —
+    a key's latest event wins — so applying a drained delta to the key
+    set as of the previous drain gives the key set now."""
+
+    __slots__ = ("added", "removed")
+
+    def __init__(self) -> None:
+        self.added: Set[str] = set()
+        self.removed: Set[str] = set()
+
+    def drain(self) -> Tuple[Set[str], Set[str]]:
+        added, removed = self.added, self.removed
+        self.added, self.removed = set(), set()
+        return added, removed
+
+
+def _note_added(watches: List[KeyWatch], keys: Sequence[str]) -> None:
+    for w in watches:
+        w.removed.difference_update(keys)
+        w.added.update(keys)
+
+
+def _note_removed(watches: List[KeyWatch], keys: Sequence[str]) -> None:
+    for w in watches:
+        w.added.difference_update(keys)
+        w.removed.update(keys)
 
 
 class _XferStats:
@@ -884,6 +918,11 @@ class LatticeArena:
         self._xfer = _XferStats()
         self._slabs: Dict[_GroupKey, _Slab] = {}
         self._key_group: Dict[str, _GroupKey] = {}
+        # the owning engine's membership watches (MergeEngine.watch_keys);
+        # every path below that adds or drops a key feeds them, once per
+        # call where the path is batched — and only when the list is
+        # non-empty, so an unwatched arena does no extra per-key work
+        self._watches: List[KeyWatch] = []
         # bumps whenever the key -> (slab, row) layout changes (new key,
         # delete, cross-group move) — read-plan caches key off it; pure
         # row-content updates (gossip, puts over existing keys) do NOT
@@ -973,6 +1012,8 @@ class LatticeArena:
             self._slabs[prev].drop(key)
         if prev != group:
             self.layout_version += 1
+            if prev is None and self._watches:
+                _note_added(self._watches, (key,))
         clock, node_id = lattice.timestamp
         self.registry.ensure((node_id,))
         slab = self.slab_for(group, arr)
@@ -982,6 +1023,8 @@ class LatticeArena:
 
     def set_raw(self, key: str, group: _GroupKey, clock: int, rank: int,
                 flat: np.ndarray) -> None:
+        """Raw row overwrite for a batched caller, which notes the keys
+        it adds to the watches itself (once per batch)."""
         prev = self._key_group.get(key)
         if prev is not None and prev != group:
             self._slabs[prev].drop(key)
@@ -1058,6 +1101,8 @@ class LatticeArena:
         self._slabs[group].drop(key)
         self._materialized.pop(key, None)
         self.layout_version += 1
+        if self._watches:
+            _note_removed(self._watches, (key,))
         return True
 
     # -- the plane wire format -------------------------------------------------
@@ -1113,6 +1158,11 @@ class LatticeArena:
         slab = self._slabs[group]
         rows = np.empty(len(keys), np.int64)
         bumped = False
+        if self._watches:
+            kg = self._key_group
+            fresh = [k for k in keys if k not in kg]
+            if fresh:
+                _note_added(self._watches, fresh)
         for i, key in enumerate(keys):
             prev = self._key_group.get(key)
             if prev is not None and prev != group:
@@ -1170,6 +1220,9 @@ class LatticeArena:
             rows[i] = row
         if fresh:
             self.layout_version += 1
+            if self._watches:
+                _note_added(self._watches,
+                            [k for k, h in zip(keys, has) if not h])
         if self._materialized:
             for key in keys:
                 self._materialized.pop(key, None)
@@ -1313,6 +1366,8 @@ class MergeEngine:
         return self.arena.get(key)
 
     def set(self, key: str, value: Lattice) -> None:
+        # a key moving between fallback and arena is noted last as added
+        # (by arena.set, or below once arena.delete noted it removed)
         if is_arena_lww(value):
             if self.fallback.pop(key, None) is not None:
                 self._fb_version += 1
@@ -1321,13 +1376,31 @@ class MergeEngine:
             self.arena.delete(key)
             if key not in self.fallback:
                 self._fb_version += 1
+                if self.arena._watches:
+                    _note_added(self.arena._watches, (key,))
             self.fallback[key] = value
 
     def delete(self, key: str) -> bool:
         if self.fallback.pop(key, None) is not None:
             self._fb_version += 1
+            if self.arena._watches:
+                _note_removed(self.arena._watches, (key,))
             return True
         return self.arena.delete(key)
+
+    # -- key membership watches ------------------------------------------------
+    def watch_keys(self) -> KeyWatch:
+        """Start recording the keys this engine gains and loses; the
+        caller drains the returned watch and drops it with
+        :meth:`unwatch_keys`."""
+        watch = KeyWatch()
+        self.arena._watches.append(watch)
+        return watch
+
+    def unwatch_keys(self, watch: KeyWatch) -> None:
+        watches = self.arena._watches
+        if watch in watches:
+            watches.remove(watch)
 
     def merge_one(self, key: str, value: Lattice) -> Lattice:
         """Per-key merge — the semantics the batched plane must match."""
@@ -1417,6 +1490,10 @@ class MergeEngine:
                 cands.insert(0, stored)  # fold starts from the stored value
             candidates.append(cands)
 
+        if self.arena._watches:
+            fresh = [k for k in keys if k not in self.arena]
+            if fresh:
+                _note_added(self.arena._watches, fresh)
         R = max(len(c) for c in candidates)
         if R == 1:  # nothing to merge against: plain insert
             for key, cands in zip(keys, candidates):
@@ -1513,6 +1590,8 @@ class MergeEngine:
         self.arena.layout_version = old.layout_version + 1
         self.device = self.arena.device
         self.ingest_planes(batch, include_sidecar=False)
+        # the same keys, on another tier: no membership change to note
+        self.arena._watches = old._watches
         return batch
 
     def _ingest_group(self, group: _GroupKey, pg: PlaneGroup,

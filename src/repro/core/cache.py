@@ -21,7 +21,7 @@ import weakref
 from collections import defaultdict
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
-from .arena import MergeEngine, vc_dominates_or_concurrent_batch
+from .arena import KeyWatch, MergeEngine, vc_dominates_or_concurrent_batch
 from .faultnet import KVSUnavailableError
 from .kvs import AnnaKVS
 from .lattices import CausalLattice, Lattice, LWWLattice
@@ -68,6 +68,12 @@ class ExecutorCache:
         self._m_hits = m.counter(f"cache.{cache_id}.hits")
         self._m_misses = m.counter(f"cache.{cache_id}.misses")
         self._m_batched_misses = m.counter(f"cache.{cache_id}.batched_misses")
+        # key-set publishing: the first publish (and the first after a
+        # recovery) sends the whole set, every later one the delta this
+        # watch collected since
+        self._keywatch: Optional[KeyWatch] = None
+        self._m_keyset_delta = m.counter("sched.keyset.delta_keys")
+        self._m_keyset_full = m.counter("sched.keyset.full")
         # weakref: the registry outlives removed caches and must not pin
         # them (their arena subscriptions would never be pruned)
         wself = weakref.ref(self)
@@ -357,7 +363,17 @@ class ExecutorCache:
         self.pending_causal = still_pending
 
     def publish_keyset(self) -> None:
-        self.kvs.publish_keyset(self.cache_id, set(self.data))
+        """Publish the key set to the KVS: in full the first time, then
+        only the keys added and removed since the last publish."""
+        if self._keywatch is None:
+            self._keywatch = self.engine.watch_keys()
+            self.kvs.publish_keyset(self.cache_id, set(self.data))
+            self._m_keyset_full.inc()
+            return
+        added, removed = self._keywatch.drain()
+        if added or removed:
+            self.kvs.update_keyset(self.cache_id, added, removed)
+            self._m_keyset_delta.inc(len(added) + len(removed))
 
     # -- failure ------------------------------------------------------------------
     def fail(self) -> None:
@@ -365,6 +381,10 @@ class ExecutorCache:
 
     def recover(self) -> None:
         self.alive = True
+        if self._keywatch is not None:
+            # the next publish is a full one
+            self.engine.unwatch_keys(self._keywatch)
+            self._keywatch = None
         self.data.clear()
         self.snapshots.clear()
         self.pending_flush.clear()
@@ -375,10 +395,6 @@ class ExecutorCache:
         # for keys this cache no longer holds.
         self.kvs.publish_keyset(self.cache_id, set())
         self.kvs.drop_cache_pushes(self.cache_id)
-
-    @property
-    def keyset(self) -> Set[str]:
-        return set(self.data)
 
     def stats(self) -> Dict[str, int]:
         return {

@@ -141,8 +141,10 @@ class AnnaKVS:
         # Invalidated whenever placement inputs change (membership,
         # per-key replication).  Entries are shared lists: never mutated.
         self._owners_cache: Dict[str, List[str]] = {}
-        # cached-keyset index (paper §4.2): key -> caches that hold it
+        # cached-keyset index (paper §4.2): key -> caches that hold it,
+        # and its reverse, cache -> keys it subscribed
         self._cache_index: Dict[str, Set[str]] = defaultdict(set)
+        self._cache_keys: Dict[str, Set[str]] = {}
         self._cache_pushes: Dict[str, PlaneBuffer] = defaultdict(PlaneBuffer)
         self._hints: Dict[str, PlaneBuffer] = defaultdict(PlaneBuffer)
         # failure plane (off by default: every data-path hook is a single
@@ -1008,15 +1010,31 @@ class AnnaKVS:
 
     # -- cache keyset index (paper §4.2) -----------------------------------------
     def publish_keyset(self, cache_id: str, keys: Set[str]) -> None:
-        # drop stale subscriptions, add new ones; prune keys whose
-        # subscriber set empties so the index does not leak dead entries
-        for key, caches in list(self._cache_index.items()):
-            if cache_id in caches and key not in keys:
+        """Replace the cache's whole subscription with ``keys``: work in
+        the sizes of its old and new key sets, not of the index."""
+        old = self._cache_keys.get(cache_id, set())
+        keys = set(keys)
+        self.update_keyset(cache_id, keys - old, old - keys)
+
+    def update_keyset(self, cache_id: str, added: Set[str],
+                      removed: Set[str]) -> None:
+        """Apply a membership delta to the cache's subscription; keys
+        whose subscriber set empties are pruned, so the index does not
+        leak dead entries."""
+        index = self._cache_index
+        for key in removed:
+            caches = index.get(key)
+            if caches is not None:
                 caches.discard(cache_id)
-            if not caches:
-                del self._cache_index[key]
-        for key in keys:
-            self._cache_index[key].add(cache_id)
+                if not caches:
+                    del index[key]
+        for key in added:
+            index[key].add(cache_id)
+        mine = self._cache_keys.setdefault(cache_id, set())
+        mine -= removed
+        mine |= added
+        if not mine:
+            del self._cache_keys[cache_id]
 
     def drain_cache_pushes(
         self,

@@ -16,8 +16,10 @@ from __future__ import annotations
 
 import random
 from collections import defaultdict
-from typing import Callable, Dict, List, Optional, Sequence, Set
+from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
 
+from .arena import KeyWatch
+from .cache import ExecutorCache
 from .dag import Dag
 from .executor import CloudburstReference, Executor
 from .kvs import AnnaKVS
@@ -92,6 +94,11 @@ class Scheduler:
         # scheduler-local indexes (paper: each scheduler constructs a local
         # index tracking the keys stored by each cache)
         self.executor_keysets: Dict[str, Set[str]] = defaultdict(set)
+        # cache -> (watch, key set as of the last refresh): the
+        # executors of one VM share their cache's set (read-only)
+        self._cache_keysets: Dict[ExecutorCache,
+                                  Tuple[KeyWatch, Set[str]]] = {}
+        self._m_keyset_full = kvs.metrics.counter("sched.keyset.full")
         self.utilization: Dict[str, float] = {}
         self.function_locations: Dict[str, List[str]] = defaultdict(list)
         self.dags: Dict[str, Dag] = {}
@@ -138,10 +145,34 @@ class Scheduler:
 
     # -- index maintenance -------------------------------------------------------------
     def refresh_index(self, window_seconds: float = 1.0) -> None:
-        """Pull cached keysets + executor metrics (published via the KVS)."""
+        """Pull cached keysets + executor metrics (published via the KVS).
+
+        Each cache's set is copied once, the first time it is seen; later
+        refreshes apply the keys it added and removed since."""
+        seen: Dict[ExecutorCache, Set[str]] = {}
         for eid, ex in self.executors.items():
-            self.executor_keysets[eid] = set(ex.cache.keyset)
+            keys = seen.get(ex.cache)
+            if keys is None:
+                keys = seen[ex.cache] = self._cache_keyset(ex.cache)
+            self.executor_keysets[eid] = keys
             self.utilization[eid] = ex.utilization(window_seconds)
+        for cache in self._cache_keysets.keys() - seen.keys():
+            watch, _ = self._cache_keysets.pop(cache)
+            cache.engine.unwatch_keys(watch)
+
+    def _cache_keyset(self, cache: ExecutorCache) -> Set[str]:
+        entry = self._cache_keysets.get(cache)
+        if entry is None:
+            watch = cache.engine.watch_keys()
+            keys = set(cache.data)
+            self._cache_keysets[cache] = (watch, keys)
+            self._m_keyset_full.inc()
+            return keys
+        watch, keys = entry
+        added, removed = watch.drain()
+        keys -= removed
+        keys |= added
+        return keys
 
     # -- per-request scheduling -----------------------------------------------------------
     def _schedulable(self, executor: Executor) -> bool:
